@@ -26,7 +26,7 @@ files carry them:
 
 Driver artifacts that wrap the bench line (``{"cmd": ..., "parsed":
 {...}}`` — the BENCH_rNN.json files) are unwrapped automatically, so
-``bench_compare.py BENCH_r05.json /tmp/BENCH_new.json`` works on the
+``bench_compare.py BENCH_r03.json /tmp/BENCH_new.json`` works on the
 checked-in history directly.
 
 Everything else (phases, window stats) is printed as an informational
@@ -36,7 +36,7 @@ Opt-in wiring: this is NOT part of tier-1 (bench numbers are machine-
 dependent); run it from CI or by hand after a bench run, e.g.::
 
     python bench.py > /tmp/BENCH_new.json
-    python scripts/bench_compare.py BENCH_r05.json /tmp/BENCH_new.json
+    python scripts/bench_compare.py BENCH_r03.json /tmp/BENCH_new.json
 
 (A shape-level smoke test lives in tests/test_aux.py so the tool itself
 cannot rot.)
@@ -143,7 +143,7 @@ def lint_gate(new: Dict) -> Optional[str]:
     summary) into every artifact; a stamp with findings means the
     number came from code violating the determinism/jit/thread
     invariants and is not comparable.  Artifacts predating the stamp
-    (BENCH_r01–r05) pass with a warning — the gate only hardens going
+    (BENCH_r01–r04) pass with a warning — the gate only hardens going
     forward."""
     lint = new.get("lint")
     if lint is None:

@@ -244,9 +244,7 @@ def test_stage_coverage_no_gaps():
 # ----------------------------------------------------- transfer coverage
 
 #: micro-traces for primitives the consensus stages don't emit; each
-#: probe must exercise its named transfer (version-alias spellings that
-#: this jax release never emits — e.g. psum vs psum2, pcast — are
-#: covered via the function-identity groups instead)
+#: probe must exercise its named transfer
 _BATTERY = [
     ("abs", lambda x: jnp.abs(x), (-5, 5)),
     ("argmin", lambda x: jnp.argmin(x), (0, 7)),
@@ -275,9 +273,8 @@ def _battery_exercised():
 
 def test_transfer_registry_fully_exercised():
     # acceptance: every registered transfer is exercised by tests.
-    # Names registered for other jax releases' spellings share their
-    # transfer function with a spelling this release does emit, so
-    # coverage is counted per transfer *function*, not per name.
+    # Aliases (e.g. "psum"/"psum_invariant") share one transfer function,
+    # so coverage is counted per transfer *function*, not per name.
     exercised = set(_audit("baseline").exercised)
     exercised |= _audit("1m").exercised
     for m in sorted(MUTATIONS):
@@ -292,7 +289,7 @@ def test_transfer_registry_fully_exercised():
     assert not missed, f"transfers never exercised: {missed}"
     # the higher-order forms are interpreted structurally, not via the
     # registry — they must be exercised too
-    assert {"pjit", "scan", "while", "cond",
+    assert {"jit", "scan", "while", "cond",
             "shard_map"} <= exercised
 
 

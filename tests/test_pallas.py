@@ -132,6 +132,27 @@ def test_pallas_bmm_matches_xla():
         assert (np.asarray(got) == np.asarray(want)).all(), (p, q, r)
 
 
+def test_bmm_or_pallas_counts_xla_fallback():
+    """A shape the grid cannot tile takes the XLA matmul, and says so in
+    the ambient registry (chip_smoke phase D fails on any such count)."""
+    from tpu_swirld import obs as obslib
+    from tpu_swirld.tpu.pallas_kernels import bmm_or_pallas
+    from tpu_swirld.tpu.pipeline import _bmm
+
+    rng = np.random.default_rng(6)
+    a = jnp.asarray(rng.random((64, 40)) < 0.3)
+    b = jnp.asarray(rng.random((40, 5)) < 0.3)
+    with obslib.enabled() as o:
+        got = bmm_or_pallas(a, b, jnp.float32, interpret=INTERPRET)
+        bmm_or_pallas(a, a.T, jnp.float32, interpret=INTERPRET)
+    assert (np.asarray(got) == np.asarray(_bmm(a, b, jnp.float32))).all()
+    counts = {
+        dict(m.labels)["shape"]: m.value
+        for m in o.registry.metrics() if m.name == "pallas_bmm_fallback"
+    }
+    assert counts == {"64x40x5": 1}
+
+
 def test_incremental_with_pallas_block_parity():
     """IncrementalConsensus with the full Pallas extension-kernel bundle
     (ancestry bmm hop + strongly-sees block) as its hot-path backend:

@@ -17,7 +17,7 @@ the **real compiled artifact**:
   (:mod:`.transfer`) that **hard-fails on unknown primitives** — there
   is no silent "assume top" unsoundness path;
 - the interpreter (:mod:`.interpret`) handles the higher-order
-  primitives the pipeline uses (``pjit``, ``scan``, ``while``, ``cond``,
+  primitives the pipeline uses (``jit``, ``scan``, ``while``, ``cond``,
   ``shard_map``) by sub-interpretation: carried loop state is solved by
   join-to-fixpoint, exact unrolling for short loops, and length-aware
   extent extrapolation for event-scale scans (a round counter over 1M

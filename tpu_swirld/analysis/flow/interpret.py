@@ -5,7 +5,7 @@
 per variable, dispatching first-order primitives through
 :mod:`.transfer` and sub-interpreting the higher-order ones itself:
 
-``pjit`` / ``closed_call`` / ``custom_jvp_call``
+``jit`` / ``closed_call`` / ``custom_jvp_call``
     straight sub-interpretation of the inner jaxpr.
 
 ``cond``
@@ -87,13 +87,9 @@ RULE_NAMES = {
 
 def _src(eqn) -> Tuple[str, int]:
     """Best user-code (file, line) for an eqn from its source_info."""
-    frames = []
-    try:
-        from jax._src import source_info_util
+    from jax._src import source_info_util
 
-        frames = list(source_info_util.user_frames(eqn.source_info))
-    except Exception:
-        pass
+    frames = list(source_info_util.user_frames(eqn.source_info.traceback))
     best = None
     for fr in frames:
         fn = getattr(fr, "file_name", "") or ""
@@ -178,14 +174,14 @@ class _Frame:
         return f"{path}:{line}"
 
     def read(self, atom) -> AbsVal:
-        import jax.core as jcore
+        import jax.extend.core as jcore
 
         if isinstance(atom, jcore.Literal):
             return _literal_absval(atom)
         return self.env[atom]
 
     def env_lookup(self, atom) -> Optional[AbsVal]:
-        import jax.core as jcore
+        import jax.extend.core as jcore
 
         if isinstance(atom, jcore.Literal):
             return _literal_absval(atom)
@@ -268,7 +264,7 @@ def _remainder_summary(a: Interval, b: Interval) -> Optional[Interval]:
 
 def _eval_higher_order(an, frame, eqn, args):
     name = eqn.primitive.name
-    if name in ("pjit", "closed_call", "core_call"):
+    if name in ("jit", "closed_call", "core_call"):
         inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
         outs, _ = _eval_closed(an, inner, args)
         if (
@@ -382,7 +378,7 @@ def _widen_unstable(carry, prev):
 def _literal_step(jx, out_atom, in_var):
     """Constant k when the body computes ``out = in_var + k`` at top
     level (the fori_loop counter pattern); None otherwise."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     if isinstance(out_atom, jcore.Literal):
         return None

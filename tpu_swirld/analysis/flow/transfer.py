@@ -84,7 +84,7 @@ TRANSFERS = {}
 
 #: higher-order primitives the interpreter sub-interprets itself.
 HIGHER_ORDER = frozenset(
-    {"pjit", "closed_call", "core_call", "scan", "while", "cond", "shard_map",
+    {"jit", "closed_call", "core_call", "scan", "while", "cond", "shard_map",
      "custom_jvp_call", "custom_vjp_call", "remat", "checkpoint"}
 )
 
@@ -92,7 +92,7 @@ HIGHER_ORDER = frozenset(
 #: integral-float exactness check (f32 tallies must stay < 2**24).
 ACCUMULATING = frozenset(
     {"add", "sub", "mul", "dot_general", "reduce_sum", "cumsum", "cumprod",
-     "scatter-add", "psum", "psum2"}
+     "scatter-add", "psum", "psum_invariant"}
 )
 
 #: primitives that run their own representability check (skip SW008 there).
@@ -408,7 +408,7 @@ def _peel(ctx, atom):
     """Follow value-preserving ``convert_element_type`` chains back to the
     underlying variable (jnp's index normalization converts to int64
     before adding the axis size)."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     for _ in range(8):
         if isinstance(atom, jcore.Literal):
@@ -428,7 +428,7 @@ def _case_as_offset_of(ctx, case_atom, base_var):
     """If `case` is `base`, or add/sub of `base` and a constant, return the
     constant offset interval; else None.  Converts between int dtypes are
     peeled on both sides."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     case_atom = _peel(ctx, case_atom)
     if isinstance(case_atom, jcore.Literal):
@@ -452,7 +452,7 @@ def _case_as_offset_of(ctx, case_atom, base_var):
 
 @register("select_n")
 def _t_select_n(ctx, eqn, args):
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     pred, cases = args[0], args[1:]
     out_dt = eqn.outvars[0].aval.dtype
@@ -593,8 +593,14 @@ def _passthrough(ctx, eqn, args):
 
 for _name in ("broadcast_in_dim", "reshape", "squeeze", "expand_dims",
               "transpose", "rev", "copy", "slice", "stop_gradient",
-              "reduce_precision", "pbroadcast", "pcast"):
+              "reduce_precision", "pbroadcast", "reshard"):
     register(_name)(_passthrough)
+
+
+@register("pvary")
+def _t_pvary(ctx, eqn, args):
+    # marks each operand as varying over mesh axes: values are unchanged
+    return [_out(eqn, j, a.iv, a.integral) for j, a in enumerate(args)]
 
 
 @register("concatenate")
@@ -746,7 +752,7 @@ def _index_component_ivs(ctx, idx_atom, idx_val, n_comp):
     ``concatenate`` along the trailing (index-vector) dim; without this,
     the whole-array interval is the join of all components and a row
     index gets checked against the column bound."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     atom = idx_atom
     d = None
@@ -897,7 +903,7 @@ def _t_dynamic_update_slice(ctx, eqn, args):
 # mesh collectives
 
 
-@register("psum", "psum2")
+@register("psum", "psum_invariant")
 def _t_psum(ctx, eqn, args):
     axes = eqn.params.get("axes", eqn.params.get("axis_name", ()))
     n = 1

@@ -53,11 +53,6 @@ from tpu_swirld.store.slab import SlabStore
 from tpu_swirld.store.streaming import StreamingConsensus
 from tpu_swirld.tpu.pipeline import _bmm, consensus_body
 
-try:                                   # moved out of experimental in new JAX
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 MEMBER_AXIS = "members"
 
 _STATIC = (
@@ -90,7 +85,7 @@ def ssm_matrix_sharded(sees, member_table, stake, tot_stake, dtype, *, mesh):
     """
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(None, None), P(MEMBER_AXIS, None), P(MEMBER_AXIS)),
         out_specs=P(None, None),
@@ -109,11 +104,8 @@ def ssm_matrix_sharded(sees, member_table, stake, tot_stake, dtype, *, mesh):
 
         # the per-device partial tally varies over the member axis; mark the
         # initial carry as varying so the fori_loop carry types line up
-        # (pcast only exists once varying-type checking does — older
-        # shard_map accepts the plain carry)
         acc0 = jnp.zeros((n, n), dtype=jnp.int32)
-        if hasattr(lax, "pcast"):
-            acc0 = lax.pcast(acc0, (MEMBER_AXIS,), to="varying")
+        acc0 = lax.pcast(acc0, (MEMBER_AXIS,), to="varying")
         acc = lax.fori_loop(0, mt.shape[0], body, acc0)
         acc = lax.psum(acc, MEMBER_AXIS)
         return 3 * acc > 2 * tot_stake
@@ -122,7 +114,8 @@ def ssm_matrix_sharded(sees, member_table, stake, tot_stake, dtype, *, mesh):
 
 
 # Module-level kernel caches.  Keyed on the mesh's PHYSICAL identity
-# (device ids + shape + axis names), never on the live Mesh object: a
+# (platform + device ids + shape + axis names; ids alone repeat across
+# platforms), never on the live Mesh object: a
 # Mesh-keyed dict pins every mesh a test or bench round ever built —
 # along with its compiled executables and device buffers — for the
 # process lifetime, and two identical meshes miss each other's entries.
@@ -132,7 +125,7 @@ _MESH_CACHE_MAX = 8
 
 def _mesh_key(mesh: Mesh):
     return (
-        tuple(int(d.id) for d in mesh.devices.flat),
+        tuple((d.platform, int(d.id)) for d in mesh.devices.flat),
         tuple(mesh.devices.shape),
         tuple(mesh.axis_names),
     )
@@ -192,7 +185,7 @@ def make_ssm_block_fn_for_mesh(mesh: Mesh):
                 stake = jnp.pad(stake, ((0, m_pad - m),))
 
             @functools.partial(
-                _shard_map,
+                jax.shard_map,
                 mesh=mesh,
                 in_specs=(
                     P(None, None),
@@ -226,8 +219,7 @@ def make_ssm_block_fn_for_mesh(mesh: Mesh):
                     return acc + stkl[mm] * hit.astype(jnp.int32)
 
                 acc0 = jnp.zeros((rows, colsl.shape[0]), dtype=jnp.int32)
-                if hasattr(lax, "pcast"):
-                    acc0 = lax.pcast(acc0, (MEMBER_AXIS,), to="varying")
+                acc0 = lax.pcast(acc0, (MEMBER_AXIS,), to="varying")
                 acc = lax.fori_loop(0, ml, body, acc0)
                 acc = lax.psum(acc, MEMBER_AXIS)
                 return (3 * acc > 2 * tot_stake) & cv[None, :]
@@ -289,7 +281,7 @@ def make_row_sharded_block_fn(mesh: Mesh, *, bmm=None):
             )
 
             @functools.partial(
-                _shard_map,
+                jax.shard_map,
                 mesh=mesh,
                 in_specs=(
                     P(axis, None),
@@ -336,8 +328,7 @@ def make_row_sharded_block_fn(mesh: Mesh, *, bmm=None):
                     return acc + stkl[mm] * hit.astype(jnp.int32)
 
                 acc0 = jnp.zeros((rows, c), dtype=jnp.int32)
-                if hasattr(lax, "pcast"):
-                    acc0 = lax.pcast(acc0, (axis,), to="varying")
+                acc0 = lax.pcast(acc0, (axis,), to="varying")
                 acc = lax.fori_loop(0, ml, body, acc0)
                 acc = lax.psum(acc, axis)
                 return (3 * acc > 2 * tot_stake) & cv[None, :]

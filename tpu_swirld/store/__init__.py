@@ -2,7 +2,7 @@
 
 The batch pipeline materializes ``bool[N, N]`` ancestry/sees slabs — ~10 GB
 at BASELINE config 5 scale (256 members / 100k events), which is why that
-config was unreachable (VERDICT r05 "event-axis blocking is roadmap text").
+config was unreachable until event-axis blocking existed.
 DAG-BFT systems scale by never holding the whole DAG's reachability state
 resident: they commit and garbage-collect a decided prefix so live state is
 proportional to the *undecided frontier* (Bullshark, arxiv 2209.05633;

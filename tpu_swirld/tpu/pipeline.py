@@ -1120,6 +1120,16 @@ def prepare_inputs(
     return arrays, statics, ts_unique
 
 
+def _fame_slots(wit_count, r_tight: int, s_max: int) -> int:
+    """Witness slots per round for the fame/order stage: the most any of
+    its ``r_tight`` rounds holds, bucketed.  Slots fill from 0, so the
+    cut drops only empty slots.  The forked fame tally costs
+    O(S^2 * members * rounds): at the worst-case capacity of config 4
+    (S = 2019) that is tens of GB, at the observed count it is small."""
+    used = int(np.max(np.asarray(wit_count[:r_tight]), initial=1))
+    return min(s_max, _bucket(used, 8))
+
+
 def _healed_capacities(ovf: int, *, r_eff: int, r_cap: int, s_eff: int,
                        s_cap: int) -> Tuple[int, int]:
     """Translate a rounds-scan overflow bitmask into grown capacities.
@@ -1327,13 +1337,15 @@ def run_consensus(
         retries += 1
     max_round = int(stage_a["max_round"])     # device -> host scalar
     r_tight = min(r_rounds, _bucket(max_round + 3, 8))
+    s_tight = _fame_slots(stage_a["wit_count"], r_tight, s_max)
+    tab_b = stage_a["wit_table"][:r_tight, :s_tight]
     stage_b = obs.stage_call(
         "pipeline.fame_order_stage",
         fame_order_stage,
         stage_a["anc"],
         stage_a["sees"],
         stage_a["ssm"],
-        stage_a["wit_table"],
+        tab_b,
         stage_a["wit_count"],
         jnp.asarray(creator),
         jnp.asarray(coin),
@@ -1345,7 +1357,7 @@ def run_consensus(
         tot_stake=tot,
         coin_period=config.coin_period,
         r_max=r_tight,
-        s_max=s_max,
+        s_max=s_tight,
         chain=chain,
         has_forks=bool(len(packed.fork_pairs)),
         matmul_dtype_name=matmul_dtype_name,
@@ -1353,7 +1365,7 @@ def run_consensus(
     out = {
         "round": stage_a["round"],
         "is_witness": stage_a["is_witness"],
-        "wit_table": stage_a["wit_table"][:r_tight],
+        "wit_table": tab_b,
         "wit_count": stage_a["wit_count"][:r_tight],
         "max_round": stage_a["max_round"],
         **stage_b,
@@ -1605,21 +1617,23 @@ def _columns_pass(
     max_round_d = jnp.max(jnp.where(jnp.arange(n_pad) < n_d, rnd_a, 0))
     max_round = int(max_round_d)
     r_tight = min(r_rounds, _bucket(max_round + 3, 8))
+    s_tight = _fame_slots(cnt_a, r_tight, s_max)
+    tab_b = tab_a[:r_tight, :s_tight]
     stage_b = obs.stage_call(
         "pipeline.fame_order_cols_stage",
         fame_order_cols_stage,
-        anc, sees, ssm_c, jnp.asarray(col_pos), tab_a, cnt_a,
+        anc, sees, ssm_c, jnp.asarray(col_pos), tab_b, cnt_a,
         creator_d, jnp.asarray(coin), stake_d,
         jnp.asarray(parents[:, 0]), jnp.asarray(t_rank),
         max_round_d, n_d,
         tot_stake=tot, coin_period=config.coin_period, r_max=r_tight,
-        s_max=s_max, chain=chain, has_forks=has_forks,
+        s_max=s_tight, chain=chain, has_forks=has_forks,
         matmul_dtype_name=matmul_dtype_name,
     )
     out = {
         "round": rnd_a,
         "is_witness": wits_a,
-        "wit_table": tab_a[:r_tight],
+        "wit_table": tab_b,
         "wit_count": cnt_a[:r_tight],
         "max_round": max_round_d,
         **stage_b,
@@ -3151,8 +3165,8 @@ class IncrementalConsensus:
         self._g_done = packed.fork_pairs.shape[0]
         tabf = out["wit_table"]
         r_tight = tabf.shape[0]
-        fam = out["famous"].reshape(r_tight, self._s_cap)
-        dec = out["fame_decided_at"].reshape(r_tight, self._s_cap)
+        fam = out["famous"].reshape(tabf.shape)
+        dec = out["fame_decided_at"].reshape(tabf.shape)
         cntf = out["wit_count"]
         cr = 0
         while cr < r_tight:
@@ -3166,7 +3180,7 @@ class IncrementalConsensus:
         self._famous_committed = {}
         fv = 0
         for r in range(cr):
-            for s in range(self._s_cap):
+            for s in range(tabf.shape[1]):
                 e = int(tabf[r, s])
                 if e < 0:
                     continue
@@ -3220,11 +3234,14 @@ class IncrementalConsensus:
         hi = min(r_tight, cr + self._r_cap)
         rows = hi - cr
         if rows > 0:
+            # the batch pass's fame table may hold fewer (empty-tail)
+            # slots than the window's capacity
+            sw = tabf.shape[1]
             tw = tabf[cr:hi].astype(np.int32)
-            self._tab_np[:rows] = np.where(tw >= 0, tw - lo, -1)
+            self._tab_np[:rows, :sw] = np.where(tw >= 0, tw - lo, -1)
             self._cnt_np[:rows] = cntf[cr:hi]
-            self._famous_np[:rows] = fam[cr:hi]
-            self._dec_np[:rows] = dec[cr:hi]
+            self._famous_np[:rows, :sw] = fam[cr:hi]
+            self._dec_np[:rows, :sw] = dec[cr:hi]
         # column store: keep retained-round witness columns
         bat_pos = aux["col_pos"]
         bat_ssm = np.asarray(aux["ssm_c"])
