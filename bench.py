@@ -39,10 +39,10 @@ All detail goes to stderr.  Environment knobs:
     BENCH_STREAM_CHUNK (2048)  BENCH_STREAM_ORACLE (4000)
     BENCH_DEFAULT_STREAM_MEMBERS (48)  BENCH_DEFAULT_STREAM_EVENTS (6000)
     BENCH_DEFAULT_STREAM_CHUNK (1024) — the default (no-flags) run's
-    always-on scaled-down streaming leg, so stream.evps and
-    stream.dispatch_overhead_s land in every artifact (0 events
-    disables); fusion/overlap knobs via SWIRLD_FUSE_CHUNKS /
-    SWIRLD_DECODE_OVERLAP / SWIRLD_DECODE_QUEUE_DEPTH.
+    always-on scaled-down streaming leg, so stream.evps lands in every
+    artifact (0 events disables); fusion/overlap knobs via
+    SWIRLD_FUSE_CHUNKS / SWIRLD_DECODE_OVERLAP /
+    SWIRLD_DECODE_QUEUE_DEPTH.
     BENCH_STREAM_REF (20000) — with --mesh: events for the in-run
     single-device reference pass (0 disables); BENCH_STREAM_SINGLE_EVPS
     supplies the reference throughput externally instead (e.g. from a
@@ -79,10 +79,10 @@ STREAM_CHUNK = int(os.environ.get("BENCH_STREAM_CHUNK", "2048"))
 STREAM_ORACLE = int(os.environ.get("BENCH_STREAM_ORACLE", "12000"))
 
 # always-on streaming leg of the DEFAULT run, config-scaled down so the
-# headline stays cheap: every artifact then carries stream.evps and
-# stream.dispatch_overhead_s for bench_compare.py's EXTRA_KEYS gates
-# (previously only --stream artifacts had them, so the fused-dispatch
-# path could regress invisibly between config-5 soaks).  0 events
+# headline stays cheap: every artifact then carries stream.evps for
+# bench_compare.py's EXTRA_KEYS gates (previously only --stream artifacts
+# had it, so the fused-dispatch path could regress invisibly between
+# config-5 soaks).  0 events
 # disables the leg; the full config-5 shape remains behind --stream.
 # Gossip arrives in batches of 4x the ingest chunk so one ingest call
 # spans several deltas — that exercises BOTH the decode-overlap worker
@@ -324,16 +324,12 @@ def run_default():
         finality["incremental"] = inc.finality.summary()
 
     # ---- always-on streaming leg (config-scaled down) ----
-    # Profiled ingest through StreamingConsensus so stream.evps and
-    # stream.dispatch_overhead_s land in EVERY artifact; decided output
-    # is parity-checked bit-identically against the batch pipeline over
-    # the same events.  Both sides of a bench_compare gate measure the
-    # same way (profiler ambient), so the numbers are comparable even
-    # though the profiler adds per-stage sync.
+    # Ingest through StreamingConsensus so stream.evps lands in EVERY
+    # artifact; decided output is parity-checked bit-identically against
+    # the batch pipeline over the same events.
     stream_out = None
     if DEFAULT_STREAM_EVENTS > 0:
         from tpu_swirld.config import SwirldConfig, resolve_stream_settings
-        from tpu_swirld.obs.profile import DispatchProfiler
         from tpu_swirld.sim import stream_gossip_dag
         from tpu_swirld.store import StreamingConsensus
 
@@ -351,34 +347,17 @@ def run_default():
 
         settings = resolve_stream_settings(s_cfg)
 
-        def _stream_pass(profiler):
+        with o.tracer.span("stream_default"), mon.phase("stream_default"):
             eng = StreamingConsensus(
                 s_members, s_stake, s_cfg,
                 ingest_chunk=DEFAULT_STREAM_CHUNK,
                 window_bucket=2048, prune_min=1024,
             )
             t0 = time.time()
-            if profiler is not None:
-                with obslib.enabled(obslib.Obs(profiler=profiler)):
-                    for ch in s_chunks:
-                        eng.ingest(ch)
-            else:
-                for ch in s_chunks:
-                    eng.ingest(ch)
-            dt = time.time() - t0
+            for ch in s_chunks:
+                eng.ingest(ch)
+            t_s = time.time() - t0
             eng.store.close()
-            return eng, dt
-
-        # pass 1 (timed, untraced): the leg's evps + parity.  Pass 2
-        # re-runs under the DispatchProfiler on the now-warm jit caches —
-        # profiling the cold pass would book every one-off compile into
-        # dispatch_overhead_s and drown the per-chunk signal being gated.
-        with o.tracer.span("stream_default"), mon.phase("stream_default"):
-            eng, t_s = _stream_pass(None)
-        prof = DispatchProfiler()
-        with o.tracer.span("stream_default_profile"), \
-                mon.phase("stream_default_profile"):
-            _eng2, _t2 = _stream_pass(prof)
         s_res = eng.result()
         got = [eng.packer.event_id(i) for i in s_res.order]
         want = [s_packed.ids[i] for i in s_ref.order]
@@ -389,19 +368,14 @@ def run_default():
             int(s_res.round[i]) == ref_round[eng.packer.event_id(i)]
             for i in range(len(s_events))
         )
-        dispatch = prof.summary()
         s_evps = DEFAULT_STREAM_EVENTS / t_s
         log(f"[stream-default] {DEFAULT_STREAM_EVENTS} ev x "
             f"{DEFAULT_STREAM_MEMBERS} members in {t_s:.2f}s = "
             f"{s_evps:.0f} ev/s fuse={settings['fuse_chunks']} "
             f"decode_overlap={settings['decode_overlap']} "
-            f"dispatch_overhead={dispatch['dispatch_overhead_s']:.3f}s "
-            f"fused_dispatches={dispatch['fused_dispatches']} "
             f"parity={s_parity}")
         stream_out = {
             "evps": round(s_evps, 1),
-            # dotted keys bench_compare.py gates directly
-            "dispatch_overhead_s": dispatch["dispatch_overhead_s"],
             "members": DEFAULT_STREAM_MEMBERS,
             "events": DEFAULT_STREAM_EVENTS,
             "chunk": DEFAULT_STREAM_CHUNK,
@@ -410,7 +384,6 @@ def run_default():
             "decoded_off_thread": eng.decoded_off_thread,
             "ordered": len(s_res.order),
             "parity": bool(s_parity),
-            "profile": dispatch,
         }
 
     phases = {k: round(v, 4) for k, v in o.tracer.phase_seconds().items()}
@@ -584,66 +557,6 @@ def run_stream(tile_budget, tile, mesh_n=0, device_tile_budget=None):
     log(f"[store] {json.dumps(stats)} budget_ok={budget_ok}"
         + (f" dev_budget_ok={dev_budget_ok}" if mesh_n else ""))
 
-    # ---- dispatch-level hot-path profile (ROADMAP item 4: measure the
-    # per-chunk dispatch / host-device cost instead of guessing).  Two
-    # single-device passes over the same stream prefix on the now-warm
-    # jit caches: one untraced (the control), one under a
-    # DispatchProfiler + tracer — the delta is the measured
-    # tracing/profiling overhead, gated <= 5% by bench_compare.py.
-    profile_events = int(os.environ.get(
-        "BENCH_STREAM_PROFILE", str(3 * STREAM_CHUNK)
-    ))
-    dispatch_out = None
-    if profile_events:
-        profile_events = min(profile_events, STREAM_EVENTS)
-        from tpu_swirld import obs as obs_mod
-        from tpu_swirld.obs.profile import DispatchProfiler
-
-        def _profile_pass(enabled_obs):
-            _m3, _s3, _k3, prof_chunks = stream_gossip_dag(
-                STREAM_MEMBERS, profile_events, STREAM_CHUNK, seed=1
-            )
-            eng = StreamingConsensus(
-                members, stake, cfg,
-                tile_budget=tile_budget, tile=tile,
-                ingest_chunk=STREAM_CHUNK, window_bucket=2048,
-                prune_min=1024,
-            )
-            t0 = time.time()
-            if enabled_obs is not None:
-                with obs_mod.enabled(enabled_obs):
-                    for chunk in prof_chunks:
-                        eng.ingest(chunk)
-            else:
-                for chunk in prof_chunks:
-                    eng.ingest(chunk)
-            dt = time.time() - t0
-            eng.store.close()
-            return dt
-
-        with mon.phase("stream_profile"):
-            t_plain = _profile_pass(None)
-            prof = DispatchProfiler()
-            t_prof = _profile_pass(obs_mod.Obs(profiler=prof))
-        overhead_ratio = max(0.0, (t_prof - t_plain) / t_plain)
-        dispatch_out = prof.summary()
-        dispatch_out["profiled_events"] = profile_events
-        dispatch_out["plain_s"] = round(t_plain, 6)
-        dispatch_out["profiled_s"] = round(t_prof, 6)
-        dispatch_out["trace_overhead_ratio"] = round(overhead_ratio, 4)
-        top = ", ".join(
-            f"{t['stage']}={t['seconds']:.3f}s/{t['calls']}x"
-            for t in dispatch_out["top_stages"]
-        )
-        log(f"[dispatch] {profile_events} ev profiled: "
-            f"wall={dispatch_out['wall_s']:.3f}s "
-            f"stage={dispatch_out['stage_s']:.3f}s "
-            f"overhead={dispatch_out['dispatch_overhead_s']:.3f}s "
-            f"h2d={dispatch_out['transfers_bytes']['h2d']} "
-            f"d2h={dispatch_out['transfers_bytes']['d2h']} "
-            f"top[{top}] "
-            f"trace_overhead={overhead_ratio:.1%}")
-
     mesh_out = None
     if mesh_n:
         # single-device reference for the scaling number: an external
@@ -737,16 +650,6 @@ def run_stream(tile_budget, tile, mesh_n=0, device_tile_budget=None):
             "oracle_prefix": n_oracle,
             "oracle_decided": len(oracle.consensus),
             "parity": bool(parity),
-            # dotted keys bench_compare.py gates directly
-            "dispatch_overhead_s": (
-                dispatch_out["dispatch_overhead_s"]
-                if dispatch_out is not None else None
-            ),
-            "trace_overhead_ratio": (
-                dispatch_out["trace_overhead_ratio"]
-                if dispatch_out is not None else None
-            ),
-            "dispatch": dispatch_out,
         },
         "finality": {
             "streaming": {

@@ -95,11 +95,6 @@ EXTRA_KEYS = [
     ("soak.tx_per_s", True),
     ("soak.submit_p99_s", False),
     ("soak.disruptions_survived", True),
-    # dispatch-profiler artifacts (bench.py --stream): the non-device
-    # per-chunk cost (wall minus stage time) the streaming engine pays —
-    # LOWER is better; a driver change that adds host work or transfer
-    # stalls per chunk regresses it even when evps holds
-    ("stream.dispatch_overhead_s", False),
     # dynamic-membership churn artifacts (bench.py --churn): events/sec
     # through the epoch-aware driver over a multi-epoch schedule (higher
     # is better — a restatement or ledger-bookkeeping slowdown shows up
@@ -111,12 +106,6 @@ EXTRA_KEYS = [
     ("churn.repack_p99_s", False),
     ("churn.epochs", True),
 ]
-
-#: artifacts whose tracing overhead exceeded this ratio are refused —
-#: the profiled sample perturbed the run too much to vouch for its
-#: numbers (ISSUE 16 acceptance: tracing keeps stream.evps within 5%)
-MAX_TRACE_OVERHEAD_RATIO = 0.05
-
 
 def unwrap(doc: Dict) -> Dict:
     """Driver artifacts wrap the bench JSON line under ``parsed``."""
@@ -222,24 +211,6 @@ def soak_gate(new: Dict) -> Optional[str]:
     )
 
 
-def trace_overhead_gate(new: Dict) -> Optional[str]:
-    """Refuse a candidate whose own profiled sample shows tracing
-    perturbing the streaming run by more than
-    :data:`MAX_TRACE_OVERHEAD_RATIO` — its dispatch-overhead and evps
-    numbers were measured under observer distortion and are not
-    comparable.  Artifacts without the stamp (pre-profiler, or profiling
-    disabled) pass untouched."""
-    ratio = _get(new, "stream.trace_overhead_ratio")
-    if ratio is None or ratio <= MAX_TRACE_OVERHEAD_RATIO:
-        return None
-    return (
-        f"candidate's tracing overhead ratio {ratio:.1%} exceeds "
-        f"{MAX_TRACE_OVERHEAD_RATIO:.0%}: the profiled sample perturbed "
-        "the run; shrink BENCH_STREAM_PROFILE or fix the profiler cost "
-        "and re-bench before gating"
-    )
-
-
 def compare(old: Dict, new: Dict, key: str, threshold: float):
     """Returns (failures, report_lines)."""
     lines = []
@@ -283,7 +254,7 @@ def main(argv=None) -> int:
     with open(args.new) as f:
         new = unwrap(json.load(f))
     for gate in (lint_gate(new), mc_gate(new), scale_audit_gate(new),
-                 trace_overhead_gate(new), soak_gate(new)):
+                 soak_gate(new)):
         if gate is not None:
             print(f"\nFAIL: {gate}", file=sys.stderr)
             return 1

@@ -160,7 +160,7 @@ def test_enabled_pipeline_records_stages_and_pad_waste():
     assert "pipeline.rounds_chunk_stage" in names
     assert "pipeline.fame_order_cols_stage" in names
     spans = {e["name"] for e in o.tracer.spans()}
-    assert "pipeline.finalize" in spans
+    assert "swirld.order" in spans
 
 
 def test_enabled_pipeline_span_count_is_stage_granular():
@@ -437,84 +437,6 @@ def test_untraced_spans_carry_no_trace_keys():
     assert t.active_context() is None
 
 
-# ------------------------------------- telemetry plane: dispatch profiler
-
-
-def test_dispatch_profiler_chunk_accounting_with_injected_clock():
-    import numpy as np
-
-    from tpu_swirld.obs.profile import DispatchProfiler
-
-    ticks = iter([100.0, 110.0])   # begin_chunk, end_chunk
-    prof = DispatchProfiler(top_k=2, clock=lambda: next(ticks))
-    prof.begin_chunk(label="c0")
-    # two dispatches: 3s stage A, 2s stage B, 1s gap between them
-    prof.record_dispatch("A", 100.0, 103.0,
-                         args=(np.zeros(4, dtype=np.uint8),))
-    prof.record_dispatch("B", 104.0, 106.0)
-    prof.record_dispatch("A", 106.0, 107.0)
-    prof.record_transfer("d2h", 32)
-    row = prof.end_chunk(n_events=7)
-    assert row["label"] == "c0" and row["n_events"] == 7
-    assert row["dispatches"] == 3
-    assert row["stage_s"] == pytest.approx(6.0)
-    assert row["wall_s"] == pytest.approx(10.0)
-    assert row["overhead_s"] == pytest.approx(4.0)   # wall - stage
-    assert row["gap_s"] == pytest.approx(1.0)        # only B<-A gap
-    assert row["h2d_bytes"] == 4 and row["d2h_bytes"] == 32
-    s = prof.summary()
-    assert s["chunks"] == 1 and s["dispatches"] == 3
-    assert s["dispatch_overhead_s"] == pytest.approx(4.0)
-    assert s["transfers_bytes"] == {"h2d": 4, "d2h": 32}
-    # ranked by total seconds, name-stable
-    assert [r["stage"] for r in s["top_stages"]] == ["A", "B"]
-    assert s["top_stages"][0]["seconds"] == pytest.approx(4.0)
-    assert s["top_stages"][0]["calls"] == 2
-
-
-def test_dispatch_profiler_gaps_reset_at_chunk_boundaries():
-    from tpu_swirld.obs.profile import DispatchProfiler
-
-    ticks = iter([0.0, 10.0, 10.0, 20.0])
-    prof = DispatchProfiler(clock=lambda: next(ticks))
-    prof.begin_chunk()
-    prof.record_dispatch("A", 1.0, 2.0)
-    prof.end_chunk()
-    prof.begin_chunk()
-    # 9 seconds since the last dispatch of chunk 0 — NOT a gap: the
-    # wait between chunks is the caller's data generation
-    prof.record_dispatch("A", 11.0, 12.0)
-    prof.end_chunk()
-    assert prof.gap_s_total == 0.0
-    assert all(c["gap_s"] == 0.0 for c in prof.chunks)
-
-
-def test_stage_call_feeds_ambient_profiler_execute_only():
-    """The obs.stage_call seam: execute dispatches feed the profiler,
-    compiles are excluded (one-time cost), and obs.to_host counts D2H."""
-    import numpy as np
-
-    from tpu_swirld.obs.profile import DispatchProfiler
-
-    import jax
-
-    @jax.jit
-    def f(x):
-        return x + 1
-
-    prof = DispatchProfiler()
-    with obs.enabled(obs.Obs(profiler=prof)):
-        prof.begin_chunk()
-        obs.stage_call("stage.f", f, np.arange(8, dtype=np.int32))  # compile
-        obs.stage_call("stage.f", f, np.arange(8, dtype=np.int32))  # execute
-        host = obs.to_host(f(np.arange(8, dtype=np.int32)))
-        prof.end_chunk(n_events=8)
-    assert prof.dispatches == 1          # the compile call was excluded
-    assert prof.h2d_bytes == 32          # one numpy arg on the execute
-    assert prof.d2h_bytes == host.nbytes
-    assert prof.chunks[0]["dispatches"] == 1
-
-
 # --------------------------------------- telemetry plane: shard merging
 
 
@@ -695,41 +617,38 @@ def test_report_cluster_dir_renders_fleet_with_na_for_old_reports(
 
 
 def test_lint_scopes_cover_new_obs_modules():
-    """obs/cluster_trace.py and obs/profile.py sit inside the SW002 and
-    SW003 scopes; profile.py is additionally in the SW003 note scope, so
-    its single wall read must carry a justified suppression."""
+    """obs/cluster_trace.py sits inside the SW002 and SW003 scopes; the
+    SW003 note scope (here soak.py) takes only a justified suppression."""
     from tpu_swirld.analysis.lint import check_source
 
     set_iter = "def f(s):\n    for x in {1, 2}:\n        pass\n"
     clock = "import time\n\ndef f():\n    return time.perf_counter(){}\n"
-    for mod in ("obs/cluster_trace.py", "obs/profile.py"):
-        assert any(
-            f.rule == "SW002"
-            for f in check_source(set_iter, module_path=mod, rules=["SW002"])
-        ), mod
-        assert any(
-            f.rule == "SW003"
-            for f in check_source(
-                clock.format(""), module_path=mod, rules=["SW003"],
-            )
-        ), mod
-    # note scope: a bare disable is NOT enough in profile.py...
+    assert any(
+        f.rule == "SW002"
+        for f in check_source(set_iter, module_path="obs/cluster_trace.py",
+                              rules=["SW002"])
+    )
+    assert any(
+        f.rule == "SW003"
+        for f in check_source(
+            clock.format(""), module_path="obs/cluster_trace.py",
+            rules=["SW003"],
+        )
+    )
+    # note scope: a bare disable is NOT enough in soak.py...
     assert check_source(
         clock.format("   # swirld-lint: disable=SW003"),
-        module_path="obs/profile.py", rules=["SW003"],
+        module_path="soak.py", rules=["SW003"],
     )
     # ...a justified one is
     assert check_source(
-        clock.format("   # swirld-lint: disable=SW003 -- profiler callsite"),
-        module_path="obs/profile.py", rules=["SW003"],
+        clock.format("   # swirld-lint: disable=SW003 -- wall schedule"),
+        module_path="soak.py", rules=["SW003"],
     ) == []
-    # and the shipped modules themselves pass the full rule set
+    # and the shipped module itself passes the full rule set
     import tpu_swirld.obs as obspkg
     from tpu_swirld.analysis.lint import lint_paths
 
     base = os.path.dirname(obspkg.__file__)
-    findings = lint_paths([
-        os.path.join(base, "cluster_trace.py"),
-        os.path.join(base, "profile.py"),
-    ])
+    findings = lint_paths([os.path.join(base, "cluster_trace.py")])
     assert findings == [], [str(f) for f in findings]

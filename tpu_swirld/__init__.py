@@ -30,7 +30,7 @@ so SURVEY.md + BASELINE.json pin the spec), redesigned TPU-first:
   by the undecided window (BASELINE config 5 at full scale).
 - ``tpu_swirld.checkpoint`` — packed-DAG, full-node, and slab-archive
   save/restore (digest-verified).
-- ``tpu_swirld.metrics`` — per-phase timers, protocol gauges, profiler.
+- ``tpu_swirld.metrics`` — per-phase timers and protocol gauges.
 - ``tpu_swirld.viz`` — per-event state export (both backends), JSON /
   Graphviz / ASCII renderers.
 

@@ -242,11 +242,10 @@ def runtime_audit(
 
     steady = obslib.compile_counts(o.registry)
     drift = _find_drift(records)
-    # the fused rounds span (stage_call_fused megadispatch) feeds the
-    # same observer seam as stage_call, so when fuse_chunks > 1 (the
-    # resolved default) the audit's recompile/drift verdict covers the
-    # K-chunk scan path — surface that coverage in the report so a
-    # config that silently fell back to per-chunk dispatch is visible
+    # when fuse_chunks > 1 (the resolved default) the audit's
+    # recompile/drift verdict covers the K-chunk rounds span — surface
+    # that coverage in the report so a config that silently fell back to
+    # per-chunk dispatch is visible
     fused_audited = "pipeline.rounds_span_stage" in records
     return {
         "engine": engine,

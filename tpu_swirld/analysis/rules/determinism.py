@@ -177,7 +177,7 @@ class UnorderedIterRule(Rule):
     scope = (
         "oracle/", "store/streaming.py", "tpu/pipeline.py", "chaos.py",
         "adversary.py", "obs/finality.py", "obs/flightrec.py",
-        "obs/cluster_trace.py", "obs/profile.py",
+        "obs/cluster_trace.py",
         "net/proxy.py", "net/traffic.py", "soak.py",
         "membership/",
     )
@@ -314,8 +314,7 @@ class WallClockRule(Rule):
     # never read wall time themselves (byte-stable sim dumps depend on it)
     scope = (
         "transport.py", "oracle/node.py", "obs/finality.py",
-        "obs/flightrec.py", "net/", "obs/cluster_trace.py",
-        "obs/profile.py", "soak.py",
+        "obs/flightrec.py", "net/", "obs/cluster_trace.py", "soak.py",
     )
     # net/ is the socket deployment edge: real deadlines, pacing, and tx
     # latency genuinely need wall time — but each read must say *why* at
@@ -323,12 +322,10 @@ class WallClockRule(Rule):
     # (``disable=SW003 -- <why>``) counts there; a bare disable or a
     # disable-file is still a finding, so the wall-clock surface of the
     # net layer stays enumerable and every entry self-documents.
-    # obs/profile.py: the dispatch profiler's single timing callsite is
-    # its one legitimate wall read — justified there, nowhere else.
     # soak.py drives real processes on a wall-clock schedule; same rule:
     # every wall read routes through frame.now()/frame.sleep() or a
     # justified line suppression.
-    note_scope = ("net/", "obs/profile.py", "soak.py")
+    note_scope = ("net/", "soak.py")
 
     _FIX = (
         "in the logical-time transport/retry layer; fix: advance the "

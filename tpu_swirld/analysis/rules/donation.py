@@ -13,9 +13,9 @@ built by :class:`tpu_swirld.analysis.lint.PackageIndex`:
 - direct: ``update_block_stage(buf, ...)`` where the stage was defined
   with ``donate_argnums``;
 - wrapped: ``obs.stage_call("name", stage, buf, ...)`` — donated
-  positions shift by +2 for the label and function arguments; the fused
-  variant ``obs.stage_call_fused("name", k, stage, buf, ...)`` shifts
-  by +3 (label, fused-chunk count, function);
+  positions shift by +2 for the label and function arguments (this
+  covers the scan-carry donation of ``rounds_span_stage``, whose
+  fixpoint caller must re-upload rather than reuse its carry);
 - factory: ``make_extend_visibility_stage(kern)(buf, ...)`` — the
   factory's inner jitted def declares the donation.
 
@@ -99,30 +99,6 @@ class DonationRule(Rule):
             ):
                 positions = tuple(
                     p + 2 for p in idx.donation_factories[inner.func.id]
-                )
-                stage = inner.func.id
-        elif (
-            (isinstance(fn, ast.Attribute) and fn.attr == "stage_call_fused")
-            or (isinstance(fn, ast.Name) and fn.id == "stage_call_fused")
-        ) and len(args) >= 3:
-            # fused wrapper: (label, fused_chunks, fn, *args) — donated
-            # positions shift by +3.  This covers the scan-carry donation
-            # shape: rounds_span_stage donates its carry slabs, and the
-            # fixpoint caller must re-upload rather than reuse them.
-            inner = args[2]
-            if isinstance(inner, ast.Name):
-                if inner.id in idx.donations:
-                    positions = tuple(
-                        p + 3 for p in idx.donations[inner.id]
-                    )
-                    stage = inner.id
-            elif (
-                isinstance(inner, ast.Call)
-                and isinstance(inner.func, ast.Name)
-                and inner.func.id in ctx_index.donation_factories
-            ):
-                positions = tuple(
-                    p + 3 for p in idx.donation_factories[inner.func.id]
                 )
                 stage = inner.func.id
         keys = []

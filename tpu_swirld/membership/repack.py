@@ -89,8 +89,8 @@ def repack_packer(packer: Packer, epoch: MemberEpoch) -> RepackStats:
         )
     packer.set_stake(epoch.stake)
     # device-side extension: same arrays pack() would snapshot, and
-    # dispatched through obs.stage_call so the dispatch profiler and
-    # the flow-audit coverage probe see the boundary like any other
+    # dispatched through obs.stage_call so the engine recorder and the
+    # flow-audit coverage probe see the boundary like any other
     # pipeline stage
     from tpu_swirld import obs
 

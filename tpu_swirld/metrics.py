@@ -3,9 +3,8 @@
 The real observability subsystem lives in :mod:`tpu_swirld.obs` (nested-span
 tracer, counter/gauge/histogram registry, Prometheus/JSON exporters, report
 CLI).  This module keeps the original lightweight surface — ``Metrics`` with
-``phase`` / ``count`` / ``snapshot``, :func:`node_gauges`,
-:func:`trace_consensus` — as a thin shim so existing call sites keep working
-unchanged; a ``Metrics`` now records into an :class:`~tpu_swirld.obs.
+``phase`` / ``count`` / ``snapshot`` and :func:`node_gauges` — as a thin
+shim so existing call sites keep working unchanged; a ``Metrics`` now records into an :class:`~tpu_swirld.obs.
 registry.Registry` (own or shared), so per-node counters and the ambient
 pipeline metrics can export through one Prometheus/JSON pipe.
 
@@ -13,9 +12,8 @@ Zero overhead when disabled (the default); enable per node with
 ``node.metrics = Metrics()`` or pass ``metrics=`` / ``tracer=`` to the
 :mod:`tpu_swirld.sim` helpers.
 
-``jax.profiler`` traces for the device pipeline are one call away:
-:func:`trace_consensus` wraps a pipeline run in a profiler trace directory
-viewable with TensorBoard/XProf.
+Any ``jax.profiler`` trace of the device pipeline shows the engines'
+phases as ``swirld.*`` annotations (:mod:`tpu_swirld.obs`).
 """
 
 from __future__ import annotations
@@ -161,14 +159,3 @@ def node_gauges(
             gauges["decided_round"]
         )
     return gauges
-
-
-def trace_consensus(packed, config=None, outdir: str = "/tmp/swirld-trace", **kw):
-    """Run the device pipeline under a jax.profiler trace (XProf viewable)."""
-    import jax
-
-    from tpu_swirld.tpu.pipeline import run_consensus
-
-    with jax.profiler.trace(outdir):
-        result = run_consensus(packed, config, **kw)
-    return result

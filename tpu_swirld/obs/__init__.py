@@ -24,8 +24,7 @@ Instrumented layers: oracle phases (``oracle/node.py::consensus_pass``),
 gossip (sync round-trips / payload bytes / events-per-sync / fork
 detections), the device pipeline stages (``tpu/pipeline.py`` — per-stage
 compile-vs-execute time, pad waste, strongly-sees column and chunk-scan
-counts), and the mesh path (``parallel.py``).  For device-internal
-profiling beyond stage granularity use ``metrics.trace_consensus`` (XProf).
+counts), the mesh path (``parallel.py``), and the engines' phases (below).
 
 Enabling
 --------
@@ -43,6 +42,33 @@ tracer nor registry when it is unset.  Enable around a region::
 
 then render with ``python -m tpu_swirld.obs report /tmp/swirld.trace.jsonl``.
 
+Engine spans
+------------
+
+Each engine entry (``StreamingConsensus.ingest``,
+``IncrementalConsensus.ingest``, ``run_consensus``, ``pack_events``)
+reads the gate once (:func:`recorder`) and installs what it finds for the
+call (:func:`call_span`); every span, tally and pull inside reads that
+one recorder.  The recorder is the ambient ``Obs``'s tracer when
+``enable()`` was called; otherwise, while a JAX profiler session runs
+(``jax.profiler.TraceAnnotation.is_enabled()``), a process-wide tracer
+(:func:`profile_recorder`) that is cleared when a new session started by
+``jax.profiler.trace`` / ``start_trace`` is first seen, holds at most
+:data:`PROFILE_MAX_EVENTS` events (``dropped`` counts the rest) and
+enters a ``TraceAnnotation`` for each span it records, so the phases show
+in the device trace an operator opens in Perfetto or TensorBoard;
+otherwise none, and every span site gets one shared no-op span.  Spans
+are phase-grained and named ``swirld.*``: ``stream_ingest``, ``batch``,
+``pass``, ``pack``, ``plan``, ``rounds``, ``fame``, ``order``,
+``retire``, ``rebase`` and ``wait`` (``on``: ``device``, ``decode`` or
+``spill``).  Each ``swirld.pass`` / ``swirld.batch`` record counts
+:data:`TALLIES`; under an enabled ``Obs`` those counts also go to the
+registry.  Under the profiler gate alone :func:`stage_call` does not
+block: device time per stage is the device trace's.  Under an enabled
+``Obs`` it blocks on every stage, so a pull there waits for nothing and
+is counted but not spanned.  Span times are on the tracer's epoch
+(``Tracer.epoch``): ``epoch_ns + 1000 * ts`` is the profiler's clock.
+
 Per-node oracle counters remain opt-in via ``node.metrics = Metrics()``
 (now a thin shim over :class:`Registry`) and ``node.tracer = Tracer()``;
 ``sim.make_simulation(..., metrics=..., tracer=...)`` wires whole
@@ -52,8 +78,12 @@ simulations.
 from __future__ import annotations
 
 import contextlib
+import contextvars
+import sys
 import time
 from typing import Optional
+
+import numpy as np
 
 from tpu_swirld.obs.finality import (  # noqa: F401
     FinalityTracker, record_batch_result,
@@ -64,29 +94,24 @@ from tpu_swirld.obs.flightrec import (  # noqa: F401
 from tpu_swirld.obs.memory import (  # noqa: F401
     MemoryMonitor, device_live_bytes,
 )
-from tpu_swirld.obs.profile import DispatchProfiler  # noqa: F401
 from tpu_swirld.obs.registry import (  # noqa: F401
     Counter, Gauge, Histogram, Registry,
 )
 from tpu_swirld.obs.tracer import (  # noqa: F401
-    NULL_TRACER, NullTracer, Tracer, load_trace,
+    NULL_SPAN, NULL_TRACER, NullTracer, Tracer, load_trace,
 )
 
 
 class Obs:
-    """A tracer + registry bundle — the unit ``enable()`` installs.
-    An optional :class:`~tpu_swirld.obs.profile.DispatchProfiler` rides
-    along; when present, every :func:`stage_call` feeds it."""
+    """A tracer + registry bundle — the unit ``enable()`` installs."""
 
     def __init__(
         self,
         tracer: Optional[Tracer] = None,
         registry: Optional[Registry] = None,
-        profiler: Optional[DispatchProfiler] = None,
     ):
         self.tracer = tracer if tracer is not None else Tracer()
         self.registry = registry if registry is not None else Registry()
-        self.profiler = profiler
 
     def save(self, path: str) -> None:
         """Write the trace plus the registry snapshot (as Chrome counter
@@ -179,6 +204,128 @@ def phase_scope(metrics, tracer, name: str):
         yield
 
 
+# ------------------------------------------------------ engine recorder
+
+#: the counters each ``swirld.pass`` / ``swirld.batch`` record carries:
+#: stage dispatches, blocking device->host pulls, rounds-scan dispatches
+#: (probes), accepted chunks or fused spans (units), and witness columns
+#: the scan found missing and added
+TALLIES = ("dispatches", "pulls", "rounds_probes", "rounds_units",
+           "columns_added")
+
+#: registry counters that mirror the tallies under an enabled Obs (the
+#: dispatches are stage_call's per-stage ``pipeline_stage_calls``)
+_TALLY_COUNTERS = {
+    "pulls": "pipeline_host_pulls_total",
+    "rounds_probes": "pipeline_chunk_scans_total",
+    "rounds_units": "pipeline_rounds_units_total",
+    "columns_added": "pipeline_scan_columns_total",
+}
+
+#: events the profiler-gated recorder keeps per session; the rest are
+#: counted in its ``dropped``
+PROFILE_MAX_EVENTS = 250_000
+
+#: the recorder of the engine call running in this thread / context
+_active: contextvars.ContextVar = contextvars.ContextVar(
+    "swirld_recorder", default=None)
+_profile_tracer: Optional[Tracer] = None
+_profile_session = None
+_annotation = None                    # jax.profiler.TraceAnnotation
+
+
+def recorder() -> Optional[Tracer]:
+    """The gate an engine entry reads once per call: the ambient Obs's
+    tracer, else the process-wide profiler recorder while a JAX profiler
+    session runs, else None (module doc)."""
+    o = _current
+    if o is not None:
+        return o.tracer
+    ann = _annotation if _annotation is not None else _bind_annotation()
+    if ann is None or not ann.is_enabled():
+        return None
+    return _session_recorder(ann)
+
+
+def _bind_annotation():
+    """``jax.profiler.TraceAnnotation`` once JAX is loaded (a process
+    without JAX runs no profiler session, so the gate stays shut)."""
+    global _annotation
+    prof = sys.modules.get("jax.profiler")
+    if prof is not None:
+        _annotation = prof.TraceAnnotation
+    return _annotation
+
+
+def _session_recorder(annotation) -> Tracer:
+    global _profile_tracer, _profile_session
+    # the session object jax.profiler.trace / start_trace holds while it
+    # runs (None for a session started through the profiler server)
+    state = getattr(sys.modules.get("jax._src.profiler"), "_profile_state",
+                    None)
+    session = getattr(state, "profile_session", None)
+    if _profile_tracer is None or session is not _profile_session:
+        _profile_session = session
+        _profile_tracer = Tracer(max_events=PROFILE_MAX_EVENTS,
+                                 annotation=annotation)
+    return _profile_tracer
+
+
+def profile_recorder() -> Optional[Tracer]:
+    """The process-wide recorder of the newest profiler session (None
+    before the first): what a trace reader aligns with the device trace."""
+    return _profile_tracer
+
+
+class _CallSpan:
+    """The outermost span of one engine call; installs its recorder as
+    the one every nested span, tally and pull reads."""
+
+    __slots__ = ("_rec", "_ctx", "_token")
+
+    def __init__(self, rec: Tracer, ctx):
+        self._rec, self._ctx, self._token = rec, ctx, None
+
+    def __enter__(self):
+        self._token = _active.set(self._rec)
+        return self._ctx.__enter__()
+
+    def __exit__(self, *exc):
+        _active.reset(self._token)
+        return self._ctx.__exit__(*exc)
+
+
+def call_span(rec: Optional[Tracer], name: str, *, tally: bool = False,
+              **args):
+    """An engine call's span on ``rec`` (from :func:`recorder`), holding
+    the :data:`TALLIES` at zero when ``tally``; the shared no-op span when
+    ``rec`` is None."""
+    if rec is None:
+        return NULL_SPAN
+    if tally:
+        args.update(dict.fromkeys(TALLIES, 0))
+    return _CallSpan(rec, rec.span(name, **args))
+
+
+def span(name: str, **args):
+    """A phase span on the engine call's recorder (no-op without one)."""
+    rec = _active.get()
+    if rec is None:
+        return NULL_SPAN
+    return rec.span(name, **args)
+
+
+def tally(key: str, n: int = 1) -> None:
+    """Count ``n`` into the innermost ``swirld.pass`` / ``swirld.batch``
+    record and, under an enabled Obs, its registry counter."""
+    rec = _active.get()
+    if rec is not None:
+        rec.tally(key, n)
+    o = _current
+    if o is not None:
+        o.registry.counter(_TALLY_COUNTERS[key]).inc(n)
+
+
 # Audit seam: tpu_swirld.analysis.jit_audit installs a callback here to
 # record every stage call's abstract signature (shape/dtype/weak_type per
 # arg) without touching values.  None in production — one global read.
@@ -193,33 +340,21 @@ def set_stage_observer(cb) -> None:
 
 
 def stage_call(name: str, fn, *args, **kw):
-    """Run a jitted stage under the ambient Obs (no-op pass-through when
-    disabled): spans the call, blocks on the result so the span measures
-    device completion, and classifies the call as ``compile`` vs
-    ``execute`` by watching the jit cache grow.
+    """Run a jitted stage, counted as a dispatch of the engine call.
+    Under the ambient Obs it also spans the call, blocks on the result so
+    the span measures device completion, and classifies the call as
+    ``compile`` vs ``execute`` by watching the jit cache grow.
 
     Enabling observability therefore synchronizes stage boundaries —
     that's the point (per-stage attribution); leave it disabled for
-    maximum-overlap production runs.
+    maximum-overlap production runs.  The profiler-gated recorder never
+    blocks: the device trace holds each stage's time.
     """
-    return _stage_call(name, 1, fn, args, kw)
-
-
-def stage_call_fused(name: str, fused_chunks: int, fn, *args, **kw):
-    """:func:`stage_call` for a megadispatch covering ``fused_chunks``
-    packed scan chunks (the fused rounds span): identical tracing and
-    compile/execute classification, but the dispatch profiler is told
-    the dispatch amortizes over ``fused_chunks`` chunks so the single
-    inter-dispatch gap is attributed per chunk (gap / K) instead of
-    making the gap distribution look artificially clean."""
-    return _stage_call(name, max(1, int(fused_chunks)), fn, args, kw)
-
-
-def _stage_call(name: str, fused_chunks: int, fn, args, kw):
     so = _stage_observer
     if so is not None:
         so(name, fn, args, kw)
-    o = current()
+    count_dispatch()
+    o = _current
     if o is None:
         return fn(*args, **kw)
     import jax
@@ -229,8 +364,7 @@ def _stage_call(name: str, fused_chunks: int, fn, args, kw):
     with o.tracer.span(name) as sp:
         out = fn(*args, **kw)
         out = jax.block_until_ready(out)
-        t1 = time.perf_counter()
-        dt = t1 - t0
+        dt = time.perf_counter() - t0
         kind = "execute"
         if c0 >= 0 and _jit_cache_size(fn) > c0:
             kind = "compile"
@@ -238,27 +372,32 @@ def _stage_call(name: str, fused_chunks: int, fn, args, kw):
     reg = o.registry
     reg.counter("pipeline_stage_seconds", {"stage": name, "kind": kind}).inc(dt)
     reg.counter("pipeline_stage_calls", {"stage": name, "kind": kind}).inc()
-    if o.profiler is not None and kind == "execute":
-        # compiles are one-time cost, not steady-state dispatch overhead
-        o.profiler.record_dispatch(
-            name, t0, t1, args=args, fused_chunks=fused_chunks
-        )
     return out
 
 
-def to_host(x, copy: bool = False):
-    """Pull a (device) array to host numpy, counting the D2H bytes into
-    the ambient dispatch profiler — the driver's pull sites route
-    through here so ``transfers_bytes.d2h`` reflects every round-trip.
-    ``copy=True`` forces a mutable owned copy (``np.array`` semantics
-    for mirrors mutated in place)."""
-    import numpy as _np
+def count_dispatch() -> None:
+    """Count one stage dispatch into the engine call's record (every
+    :func:`stage_call`, and a stage the caller dispatches directly)."""
+    rec = _active.get()
+    if rec is not None:
+        rec.tally("dispatches")
 
-    arr = _np.array(x) if copy else _np.asarray(x)
-    o = current()
-    if o is not None and o.profiler is not None:
-        o.profiler.record_transfer("d2h", arr.nbytes)
-    return arr
+
+def to_host(x, copy: bool = False):
+    """Pull a (device) array to host numpy: the engines' blocking pulls
+    route through here, so each is counted and, on the profiler-gated
+    recorder, spanned as ``swirld.wait{on=device}`` (under an enabled Obs
+    :func:`stage_call` has blocked already, so a pull waits for nothing).
+    ``copy=True`` forces a mutable owned copy (``np.array`` semantics for
+    mirrors mutated in place)."""
+    rec = _active.get()
+    if rec is None and _current is None:
+        return np.array(x) if copy else np.asarray(x)
+    tally("pulls")
+    if _current is not None:
+        return np.array(x) if copy else np.asarray(x)
+    with rec.span("swirld.wait", on="device"):
+        return np.array(x) if copy else np.asarray(x)
 
 
 def _jit_cache_size(fn) -> int:

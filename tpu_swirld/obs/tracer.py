@@ -9,11 +9,20 @@ file — using the Chrome trace-event "complete" form (``ph: "X"``)::
 ``ts``/``dur`` are microseconds on the tracer's *monotonic* clock
 (``time.perf_counter`` relative to the tracer epoch — immune to wall-clock
 steps); the wall-clock start time rides in ``args.wall_s`` so traces can be
-correlated with external logs.  ``args.depth`` records the nesting level at
+correlated with external logs.  The epoch is taken as a pair at
+construction (``epoch_ns`` = ``time.time_ns()``, ``epoch_perf_ns`` =
+``time.perf_counter_ns()``), so ``epoch_ns + 1000 * ts`` is a span's start
+in nanoseconds on the host's realtime clock — the clock the JAX profiler
+stamps its host events with.  ``args.depth`` records the nesting level at
 emit time (Chrome infers nesting from ts/dur overlap; the report CLI uses
 the explicit depth).  A file of these lines loads directly into
 ``chrome://tracing`` / Perfetto after wrapping in ``[...]`` —
 :func:`save_chrome` writes that wrapped form, :meth:`Tracer.save` the JSONL.
+
+Profiler annotations: a tracer built with ``annotation=`` (a
+``jax.profiler.TraceAnnotation``-shaped factory) also enters an annotation
+of the same name for every span it records, so the span shows in a device
+trace opened in Perfetto or TensorBoard.
 
 Disabled mode: :data:`NULL_TRACER` answers every ``span()`` call with one
 shared no-op context manager — no allocation, no timestamps, nothing
@@ -67,7 +76,7 @@ class _SpanHandle:
 
     __slots__ = (
         "name", "args", "_t0_mono", "_wall_s",
-        "span_id", "trace_id", "parent_id",
+        "span_id", "trace_id", "parent_id", "_ann",
     )
 
     def __init__(self, name: str, args: Dict, t0_mono: float, wall_s: float,
@@ -80,6 +89,7 @@ class _SpanHandle:
         self.span_id = 0
         self.trace_id = trace_id
         self.parent_id = parent_id
+        self._ann = None
 
 
 class _NullSpan:
@@ -100,7 +110,7 @@ class _NullSpan:
         return False
 
 
-_NULL_SPAN = _NullSpan()
+NULL_SPAN = _NullSpan()
 
 
 class NullTracer:
@@ -111,12 +121,15 @@ class NullTracer:
     events: List[Dict] = []
 
     def span(self, name: str, **args):
-        return _NULL_SPAN
+        return NULL_SPAN
 
     def span_under(self, name: str, ctx=None, **args):
-        return _NULL_SPAN
+        return NULL_SPAN
 
     def instant(self, name: str, **args) -> None:
+        pass
+
+    def tally(self, key: str, n: int = 1) -> None:
         pass
 
     def active_context(self):
@@ -153,6 +166,9 @@ class _SpanCtx:
                 h.trace_id = top.trace_id
             if h.parent_id is None:
                 h.parent_id = top.span_id
+        if t._annotation is not None:
+            h._ann = t._annotation(h.name)
+            h._ann.__enter__()
         h._wall_s = time.time()
         h._t0_mono = time.perf_counter()   # re-stamped at entry, not creation
         t._stack.append(h)
@@ -162,6 +178,8 @@ class _SpanCtx:
         t = self._tracer
         h = t._stack.pop()
         end = time.perf_counter()
+        if h._ann is not None:
+            h._ann.__exit__(*exc)
         args = dict(
             h.args, depth=len(t._stack), wall_s=round(h._wall_s, 6),
             span_id=h.span_id,
@@ -190,15 +208,20 @@ class Tracer:
     enabled = True
 
     def __init__(self, pid: int = 0, tid: int = 0,
-                 max_events: Optional[int] = None):
+                 max_events: Optional[int] = None, annotation=None):
         self.pid = pid
         self.tid = tid
         self.events: List[Dict] = []
         self.dropped = 0
         self.max_events = max_events
+        self._annotation = annotation
         self._stack: List[_SpanHandle] = []
-        self._epoch_mono = time.perf_counter()
-        self._epoch_wall = time.time()
+        # the epoch as a (realtime, monotonic) pair read back to back:
+        # epoch_ns + 1000 * ts maps a span onto the profiler's clock
+        self.epoch_ns = time.time_ns()
+        self.epoch_perf_ns = time.perf_counter_ns()
+        self._epoch_mono = self.epoch_perf_ns * 1e-9
+        self._epoch_wall = self.epoch_ns * 1e-9
         self._span_seq = 0
 
     # ------------------------------------------------------------ recording
@@ -216,6 +239,15 @@ class Tracer:
             self.dropped += 1
             return
         self.events.append(event)
+
+    def tally(self, key: str, n: int = 1) -> None:
+        """Add ``n`` to ``key`` in the innermost open span whose args hold
+        that key (a per-call counter opened at zero); nothing when no open
+        span holds it."""
+        for h in reversed(self._stack):
+            if key in h.args:
+                h.args[key] += n
+                return
 
     def span(self, name: str, **args) -> _SpanCtx:
         """Context manager timing a nested span.  Yields a handle whose
@@ -328,11 +360,17 @@ class Tracer:
             for e in self.events:
                 f.write(json.dumps(e) + "\n")
 
+    def epoch(self) -> Dict[str, int]:
+        """The tracer's epoch: ``epoch_ns + 1000 * ts`` is a span's start
+        on the host's realtime clock (the JAX profiler's)."""
+        return {"epoch_ns": self.epoch_ns, "epoch_perf_ns": self.epoch_perf_ns}
+
     def save_chrome(self, path: str) -> None:
         """The ``{"traceEvents": [...]}`` wrapped form chrome://tracing and
-        Perfetto open directly."""
+        Perfetto open directly; the epoch rides in ``otherData``."""
         with open(path, "w") as f:
-            json.dump({"traceEvents": self.events}, f)
+            json.dump({"traceEvents": self.events, "otherData": self.epoch()},
+                      f)
 
 
 def load_trace(path: str) -> List[Dict]:

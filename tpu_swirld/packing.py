@@ -26,6 +26,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from tpu_swirld import obs
 from tpu_swirld.oracle.event import Event
 
 
@@ -310,12 +311,16 @@ def pack_events(
     members: Sequence[bytes],
     stake: Optional[Sequence[int]] = None,
 ) -> PackedDAG:
-    """Pack a topologically ordered event sequence in one shot."""
-    if stake is None:
-        stake = [1] * len(members)
-    p = Packer(members, stake)
-    p.extend(events)
-    return p.pack()
+    """Pack a topologically ordered event sequence in one shot: one
+    ``swirld.pack`` record on the engine recorder
+    (:func:`tpu_swirld.obs.recorder`)."""
+    with obs.call_span(obs.recorder(), "swirld.pack") as sp:
+        if stake is None:
+            stake = [1] * len(members)
+        p = Packer(members, stake)
+        p.extend(events)
+        sp.args["events"] = len(p)
+        return p.pack()
 
 
 def pack_node(node) -> PackedDAG:

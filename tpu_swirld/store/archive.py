@@ -178,6 +178,11 @@ class SlabArchive:
     def pending_batches(self) -> int:
         return self._q.qsize() if self._q is not None else 0
 
+    @property
+    def queue_full(self) -> bool:
+        """True while a spill would block on the full queue."""
+        return self._q is not None and self._q.full()
+
     def _row_bool(self, e: int) -> np.ndarray:
         """Decompress row ``e`` to a bool[e + 1] ancestry bitmap (LRU
         cached — parents of spilled rows and widening re-fetches are

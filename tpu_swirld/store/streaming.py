@@ -49,8 +49,6 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-import jax.numpy as jnp
-
 from tpu_swirld import obs
 from tpu_swirld.config import resolve_stream_settings
 from tpu_swirld.packing import chunk_slices, prepare_events
@@ -129,7 +127,16 @@ class StreamingConsensus(IncrementalConsensus):
         """Split the delta into bounded chunks and stream them through the
         parent pass.  Commit boundaries never influence outputs (the
         parent's contract), so the split is pure memory hygiene: the
-        cold-start rebase and every extension pass stay chunk-sized."""
+        cold-start rebase and every extension pass stay chunk-sized.  The
+        call is one ``swirld.stream_ingest`` record on the engine recorder
+        (:func:`tpu_swirld.obs.recorder`), holding one ``swirld.pass`` per
+        chunk."""
+        with obs.call_span(obs.recorder(), "swirld.stream_ingest") as sp:
+            st = self._stream_ingest(events)
+            sp.args["passes"] = st["ingest_chunks"]
+        return st
+
+    def _stream_ingest(self, events) -> Dict:
         arch = self.store.archive
         t0 = time.perf_counter()
         stall0 = arch.stall_seconds
@@ -214,7 +221,8 @@ class StreamingConsensus(IncrementalConsensus):
             for _ in range(min(self._decode_depth, len(slices))):
                 submit_next()
             while futs:
-                pairs = futs.popleft().result()   # drain barrier
+                with obs.span("swirld.wait", on="decode"):
+                    pairs = futs.popleft().result()   # drain barrier
                 submit_next()                     # keep the queue full
                 self._staged = pairs
                 self.decoded_off_thread += len(pairs)
@@ -301,7 +309,7 @@ class StreamingConsensus(IncrementalConsensus):
         # own buffer, so the donated prune roll that follows is safe)
         rows = self._anc_d[:d, :w_used]
         parents = np.asarray(self.packer.window_view(lo, lo + d)[0])
-        self.store.spill(lo, parents, rows)
+        self._spill(self.store.spill, lo, parents, rows)
 
     def _on_roll(self, dr: int) -> None:
         lo, base = self._lo, self._r_base
@@ -331,7 +339,7 @@ class StreamingConsensus(IncrementalConsensus):
             # slice on device: pull only the newly decided rows, not the
             # whole bool[N, N] slab (lazy — the pack worker materializes)
             rows = aux["anc"][arch.n_rows : lo]
-            self.store.spill_full(arch.n_rows, rows)
+            self._spill(self.store.spill_full, arch.n_rows, rows)
         tabf = out["wit_table"]
         famf = out["famous"].reshape(tabf.shape)
         decf = out["fame_decided_at"].reshape(tabf.shape)
@@ -346,6 +354,15 @@ class StreamingConsensus(IncrementalConsensus):
                 dec.append(int(decf[r, s]))
             arch.retire_round(r, evs, fam, dec)
         self._round_hi = max(self._round_hi, self._r_base)
+
+    def _spill(self, spill, *args) -> None:
+        """Hand rows to the archive; a full spill queue blocks the pass
+        until the pack worker frees a slot (``swirld.wait{on=spill}``)."""
+        if self.store.archive.queue_full:
+            with obs.span("swirld.wait", on="spill"):
+                spill(*args)
+        else:
+            spill(*args)
 
     # ---------------------------------------------------- rebase routing
 
@@ -362,6 +379,7 @@ class StreamingConsensus(IncrementalConsensus):
                     if not need:
                         self.widen_rebases += 1
                         self._widen_answered = True
+                        self._rebase_kind = "widen"
                         self._latency_phase = "widened"
                         o = obs.current()
                         if o is not None:
@@ -429,7 +447,7 @@ class StreamingConsensus(IncrementalConsensus):
         # warm the archive's decompression cache while the device pulls
         # below drain — the widening's fetch then hits hot rows
         arch.prefetch(lo2, lo)
-        # ---- host pulls of the live window (profiler-counted D2H)
+        # ---- host pulls of the live window (counted, swirld.wait spans)
         anc_cur = obs.to_host(self._anc_d)
         sees_cur = obs.to_host(self._sees_d) if has_forks else anc_cur
         ssm_cur = obs.to_host(self._ssm_d)

@@ -61,7 +61,6 @@ threshold at 0.5.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import math
@@ -87,16 +86,6 @@ INT32_MAX = np.iinfo(np.int32).max
 # slots were exhausted (OVF_SLOT).
 OVF_ROUND = 1
 OVF_SLOT = 2
-
-
-def _maybe_span(o, name: str, **args):
-    """A tracer span under the ambient Obs, or a no-op when disabled.
-
-    Stage-granular only — never called per event, so the disabled path
-    costs one None check per *stage*."""
-    if o is None:
-        return contextlib.nullcontext()
-    return o.tracer.span(name, **args)
 
 
 def _record_shapes(o, *, n: int, n_pad: int, statics: Dict) -> None:
@@ -1126,7 +1115,7 @@ def _fame_slots(wit_count, r_tight: int, s_max: int) -> int:
     cut drops only empty slots.  The forked fame tally costs
     O(S^2 * members * rounds): at the worst-case capacity of config 4
     (S = 2019) that is tens of GB, at the observed count it is small."""
-    used = int(np.max(np.asarray(wit_count[:r_tight]), initial=1))
+    used = int(np.max(obs.to_host(wit_count[:r_tight]), initial=1))
     return min(s_max, _bucket(used, 8))
 
 
@@ -1191,12 +1180,24 @@ def run_consensus(
     BLAKE2b(whiten || id)) to produce the total order.  With ``mesh`` (a
     1-D member-axis ``jax.sharding.Mesh``), the strongly-sees phase is
     sharded over the mesh with psum stake aggregation
-    (:mod:`tpu_swirld.parallel`).
+    (:mod:`tpu_swirld.parallel`).  The call is one ``swirld.batch``
+    record on the engine recorder (:func:`tpu_swirld.obs.recorder`).
     """
-    arrays, statics, ts_unique = prepare_inputs(
-        packed, config, block=block, r_max=r_max, s_max=s_max,
-        matmul_dtype_name=matmul_dtype_name,
-    )
+    with obs.call_span(obs.recorder(), "swirld.batch", tally=True):
+        return _run_consensus(
+            packed, config, block=block, r_max=r_max, s_max=s_max,
+            matmul_dtype_name=matmul_dtype_name, mesh=mesh,
+            use_pallas_ssm=use_pallas_ssm, ssm_mode=ssm_mode,
+        )
+
+
+def _run_consensus(packed, config, *, block, r_max, s_max,
+                   matmul_dtype_name, mesh, use_pallas_ssm, ssm_mode):
+    with obs.span("swirld.plan"):
+        arrays, statics, ts_unique = prepare_inputs(
+            packed, config, block=block, r_max=r_max, s_max=s_max,
+            matmul_dtype_name=matmul_dtype_name,
+        )
     config = config or SwirldConfig(n_members=packed.n_members)
     n = packed.n
     o = obs.current()
@@ -1267,7 +1268,7 @@ def run_consensus(
                 has_forks=bool(len(packed.fork_pairs)),
                 matmul_dtype_name=matmul_dtype_name,
             )
-            out = jax.tree.map(np.asarray, out)  # blocks on device completion
+            out = jax.tree.map(obs.to_host, out)  # blocks on the device
             ovf = int(out["overflow"])
             if not ovf:
                 break
@@ -1278,8 +1279,7 @@ def run_consensus(
             retries += 1
         t_device = time.perf_counter() - t_dev0
         t_fin0 = time.perf_counter()
-        with _maybe_span(o, "pipeline.finalize"):
-            result = finalize_order(packed, out, ts_unique)
+        result = _finalize_spanned(packed, out, ts_unique)
         result.timings = {
             "device_and_dispatch": round(t_device, 6),
             "finalize_host": round(time.perf_counter() - t_fin0, 6),
@@ -1327,7 +1327,7 @@ def run_consensus(
             has_forks=bool(len(packed.fork_pairs)),
             matmul_dtype_name=matmul_dtype_name,
         )
-        ovf = int(np.asarray(stage_a["overflow"]))
+        ovf = int(obs.to_host(stage_a["overflow"]))
         if not ovf:
             break
         r_rounds, s_max = _healed_capacities(
@@ -1335,7 +1335,7 @@ def run_consensus(
             s_cap=parents.shape[0],
         )
         retries += 1
-    max_round = int(stage_a["max_round"])     # device -> host scalar
+    max_round = int(obs.to_host(stage_a["max_round"]))
     r_tight = min(r_rounds, _bucket(max_round + 3, 8))
     s_tight = _fame_slots(stage_a["wit_count"], r_tight, s_max)
     tab_b = stage_a["wit_table"][:r_tight, :s_tight]
@@ -1370,11 +1370,10 @@ def run_consensus(
         "max_round": stage_a["max_round"],
         **stage_b,
     }
-    out = jax.tree.map(np.asarray, out)       # blocks on device completion
+    out = jax.tree.map(obs.to_host, out)      # blocks on the device
     t_device = time.perf_counter() - t_dev0
     t_fin0 = time.perf_counter()
-    with _maybe_span(o, "pipeline.finalize"):
-        result = finalize_order(packed, out, ts_unique)
+    result = _finalize_spanned(packed, out, ts_unique)
     result.timings = {
         "device_and_dispatch": round(t_device, 6),
         "finalize_host": round(time.perf_counter() - t_fin0, 6),
@@ -1399,11 +1398,9 @@ def _run_consensus_columns(
     )
     t_device = time.perf_counter() - t_dev0
     t_fin0 = time.perf_counter()
-    with _maybe_span(o, "pipeline.finalize"):
-        result = finalize_order(packed, out, ts_unique)
+    result = _finalize_spanned(packed, out, ts_unique)
     if o is not None:
         o.registry.counter("pipeline_ssm_columns_total").inc(aux["n_cols"])
-        o.registry.counter("pipeline_chunk_scans_total").inc(aux["n_scans"])
     result.timings = {
         "device_and_dispatch": round(t_device, 6),
         "finalize_host": round(time.perf_counter() - t_fin0, 6),
@@ -1450,7 +1447,6 @@ def _columns_pass(
         ssm_block_fn = functools.partial(
             obs.stage_call, "pipeline.ssm_block_stage", ssm_block_stage
         )
-    o = obs.current()
     parents_d = jnp.asarray(parents)
     creator_d = jnp.asarray(creator)
     stake_d = jnp.asarray(stake)
@@ -1525,6 +1521,7 @@ def _columns_pass(
             )
         for j, e in enumerate(events):
             col_pos[e] = n_cols + j
+        obs.count_dispatch()
         ssm_c = update_block_stage(
             ssm_c, part, np.int32(row0), np.int32(n_cols)
         )
@@ -1549,96 +1546,106 @@ def _columns_pass(
     if r_cap is None:
         r_cap = max(int(config.max_rounds), r_rounds)
     overflow_retries = 0
-    while True:
-        state = (
-            jnp.zeros((n_pad,), dtype=jnp.int32),
-            jnp.zeros((n_pad,), dtype=bool),
-            jnp.full((r_rounds, s_max), -1, dtype=jnp.int32),
-            jnp.zeros((r_rounds,), dtype=jnp.int32),
-            jnp.zeros((), dtype=jnp.int32),
-        )
-        for start in range(0, n_pad, chunk_size):
-            start_d = jnp.asarray(start, dtype=jnp.int32)
-            # each failed attempt adds at least one column, and a chunk can
-            # register at most chunk_size witnesses, so this bound is safe
-            # even for degenerate one-round-per-event DAGs (2-member gossip)
-            for _attempt in range(chunk_size + 1):
-                out = obs.stage_call(
-                    "pipeline.rounds_chunk_stage",
-                    rounds_chunk_stage,
-                    parents_d, ssm_c, jnp.asarray(col_pos), creator_d,
-                    stake_d, n_d, *state, start_d,
-                    jnp.zeros((), dtype=jnp.int32),
-                    tot_stake=tot, r_max=r_rounds, s_max=s_max,
-                    has_forks=has_forks, chunk=chunk_size,
-                )
-                n_scans += 1
-                tab = np.asarray(out[2])
-                registered = np.unique(tab[tab >= 0])
-                missing = registered[col_pos[registered] < 0]
-                if missing.size == 0:
-                    state = out
-                    break
-                rnd_np = np.asarray(out[0])
-                # was a missing witness's round queried later in this chunk?
-                ce = np.arange(start, start + chunk_size, dtype=np.int64)
-                p = parents_np[ce]
-                r0 = np.where(
-                    p[:, 0] < 0,
-                    -1,
-                    np.maximum(rnd_np[np.maximum(p[:, 0], 0)],
-                               rnd_np[np.maximum(p[:, 1], 0)]),
-                )
-                affected = False
-                for w in missing:
-                    if w < start:   # registered in an earlier chunk state?
-                        affected = True  # (shouldn't happen; be safe)
+    with obs.span("swirld.rounds"):
+        while True:
+            state = (
+                jnp.zeros((n_pad,), dtype=jnp.int32),
+                jnp.zeros((n_pad,), dtype=bool),
+                jnp.full((r_rounds, s_max), -1, dtype=jnp.int32),
+                jnp.zeros((r_rounds,), dtype=jnp.int32),
+                jnp.zeros((), dtype=jnp.int32),
+            )
+            for start in range(0, n_pad, chunk_size):
+                start_d = jnp.asarray(start, dtype=jnp.int32)
+                # each failed attempt adds at least one column, and a chunk
+                # can register at most chunk_size witnesses, so this bound
+                # is safe even for degenerate one-round-per-event DAGs
+                # (2-member gossip)
+                for _attempt in range(chunk_size + 1):
+                    out = obs.stage_call(
+                        "pipeline.rounds_chunk_stage",
+                        rounds_chunk_stage,
+                        parents_d, ssm_c, jnp.asarray(col_pos), creator_d,
+                        stake_d, n_d, *state, start_d,
+                        jnp.zeros((), dtype=jnp.int32),
+                        tot_stake=tot, r_max=r_rounds, s_max=s_max,
+                        has_forks=has_forks, chunk=chunk_size,
+                    )
+                    n_scans += 1
+                    obs.tally("rounds_probes")
+                    tab = obs.to_host(out[2])
+                    registered = np.unique(tab[tab >= 0])
+                    missing = registered[col_pos[registered] < 0]
+                    if missing.size == 0:
+                        state = out
+                        obs.tally("rounds_units")
                         break
-                    later = ce > w
-                    if np.any(later & (r0 == rnd_np[w])):
-                        affected = True
+                    rnd_np = obs.to_host(out[0])
+                    # was a missing witness's round queried later in this
+                    # chunk?
+                    ce = np.arange(start, start + chunk_size, dtype=np.int64)
+                    p = parents_np[ce]
+                    r0 = np.where(
+                        p[:, 0] < 0,
+                        -1,
+                        np.maximum(rnd_np[np.maximum(p[:, 0], 0)],
+                                   rnd_np[np.maximum(p[:, 1], 0)]),
+                    )
+                    affected = False
+                    for w in missing:
+                        if w < start:   # registered in an earlier chunk?
+                            affected = True  # (shouldn't happen; be safe)
+                            break
+                        later = ce > w
+                        if np.any(later & (r0 == rnd_np[w])):
+                            affected = True
+                            break
+                    add_columns([int(e) for e in missing])
+                    obs.tally("columns_added", len(missing))
+                    if not affected:
+                        state = out
+                        obs.tally("rounds_units")
                         break
-                add_columns([int(e) for e in missing])
-                if not affected:
-                    state = out
-                    break
-            else:
-                raise RuntimeError("witness-column chunk did not converge")
-            if int(np.asarray(state[4])):
-                break               # overflow: stop scanning, grow, retry
-        ovf = int(np.asarray(state[4]))
-        if not ovf:
-            break
-        r_rounds, s_max = _healed_capacities(
-            ovf, r_eff=r_rounds, r_cap=r_cap, s_eff=s_max, s_cap=n_pad,
-        )
-        overflow_retries += 1
+                else:
+                    raise RuntimeError(
+                        "witness-column chunk did not converge")
+                if int(obs.to_host(state[4])):
+                    break               # overflow: stop scanning, grow, retry
+            ovf = int(obs.to_host(state[4]))
+            if not ovf:
+                break
+            r_rounds, s_max = _healed_capacities(
+                ovf, r_eff=r_rounds, r_cap=r_cap, s_eff=s_max,
+                s_cap=n_pad,
+            )
+            overflow_retries += 1
     rnd_a, wits_a, tab_a, cnt_a, _overflow_a = state
-    max_round_d = jnp.max(jnp.where(jnp.arange(n_pad) < n_d, rnd_a, 0))
-    max_round = int(max_round_d)
-    r_tight = min(r_rounds, _bucket(max_round + 3, 8))
-    s_tight = _fame_slots(cnt_a, r_tight, s_max)
-    tab_b = tab_a[:r_tight, :s_tight]
-    stage_b = obs.stage_call(
-        "pipeline.fame_order_cols_stage",
-        fame_order_cols_stage,
-        anc, sees, ssm_c, jnp.asarray(col_pos), tab_b, cnt_a,
-        creator_d, jnp.asarray(coin), stake_d,
-        jnp.asarray(parents[:, 0]), jnp.asarray(t_rank),
-        max_round_d, n_d,
-        tot_stake=tot, coin_period=config.coin_period, r_max=r_tight,
-        s_max=s_tight, chain=chain, has_forks=has_forks,
-        matmul_dtype_name=matmul_dtype_name,
-    )
-    out = {
-        "round": rnd_a,
-        "is_witness": wits_a,
-        "wit_table": tab_b,
-        "wit_count": cnt_a[:r_tight],
-        "max_round": max_round_d,
-        **stage_b,
-    }
-    out = jax.tree.map(np.asarray, out)
+    with obs.span("swirld.fame"):
+        max_round_d = jnp.max(jnp.where(jnp.arange(n_pad) < n_d, rnd_a, 0))
+        max_round = int(obs.to_host(max_round_d))
+        r_tight = min(r_rounds, _bucket(max_round + 3, 8))
+        s_tight = _fame_slots(cnt_a, r_tight, s_max)
+        tab_b = tab_a[:r_tight, :s_tight]
+        stage_b = obs.stage_call(
+            "pipeline.fame_order_cols_stage",
+            fame_order_cols_stage,
+            anc, sees, ssm_c, jnp.asarray(col_pos), tab_b, cnt_a,
+            creator_d, jnp.asarray(coin), stake_d,
+            jnp.asarray(parents[:, 0]), jnp.asarray(t_rank),
+            max_round_d, n_d,
+            tot_stake=tot, coin_period=config.coin_period, r_max=r_tight,
+            s_max=s_tight, chain=chain, has_forks=has_forks,
+            matmul_dtype_name=matmul_dtype_name,
+        )
+        out = {
+            "round": rnd_a,
+            "is_witness": wits_a,
+            "wit_table": tab_b,
+            "wit_count": cnt_a[:r_tight],
+            "max_round": max_round_d,
+            **stage_b,
+        }
+        out = jax.tree.map(obs.to_host, out)
     aux = {
         "anc": anc, "sees": sees, "ssm_c": ssm_c,
         "col_pos": col_pos, "n_cols": n_cols, "w_cap": w_cap,
@@ -1665,6 +1672,15 @@ def _whiten_sigs(sigs) -> bytes:
     for s in sigs:
         w = xor_bytes(w, s)
     return w
+
+
+def _finalize_spanned(packed, out, ts_unique) -> ConsensusResult:
+    """:func:`finalize_order` as the engine call's ``swirld.order``
+    phase (the host tie-hash and sort)."""
+    with obs.span("swirld.order") as sp:
+        result = finalize_order(packed, out, ts_unique)
+        sp.args["ordered"] = len(result.order)
+    return result
 
 
 def finalize_order(
@@ -2100,6 +2116,7 @@ class IncrementalConsensus:
         self.rebases = 0
         self.recompiles_hint = 0
         self.overflow_heals = 0   # capacity growths absorbed by rebases
+        self._rebase_kind = "full"  # how the last rebase was answered
         self.finality = None      # obs.FinalityTracker: per-event
                                   # lifecycle (births at ingest, decided
                                   # at commit — see _stats)
@@ -2211,16 +2228,22 @@ class IncrementalConsensus:
         Returns a per-pass stats dict: ``new_events``, ``ordered`` (the
         packed indices newly committed to the total order, in order),
         ``window_size``, ``pruned_prefix``, ``rebased``, ``seconds``.
+        The pass is one ``swirld.pass`` record on the engine recorder
+        (:func:`tpu_swirld.obs.recorder`).
         """
+        with obs.call_span(obs.recorder(), "swirld.pass", tally=True) as sp:
+            st = self._ingest(events)
+            sp.args.update(n_new=st["new_events"], ordered=len(st["ordered"]),
+                           rebased=st["rebased"])
+        return st
+
+    def _ingest(self, events) -> Dict:
         t0 = time.perf_counter()
-        _o = obs.current()
-        if _o is not None and _o.profiler is not None:
-            # one profiler chunk per pass: _stats() closes it, so every
-            # return path yields a dispatch-overhead breakdown row
-            _o.profiler.begin_chunk()
         n_before = len(self.packer)
-        self._pack_delta(events)
-        n_total = len(self.packer)
+        with obs.span("swirld.pack") as sp:
+            self._pack_delta(events)
+            n_total = len(self.packer)
+            sp.args["events"] = n_total - n_before
         if self.finality is not None and n_total > n_before:
             # birth = the tick this ingest chunk entered the driver; the
             # tracker's clock decides the unit (logical tick vs seconds)
@@ -2231,7 +2254,7 @@ class IncrementalConsensus:
         if not self._initialized:
             # the cold-start build is a rebase mechanically but not a
             # *failed incremental attempt* — it never feeds the guard
-            ordered = self._rebase()
+            ordered = self._rebase_spanned()
             return self._stats(n_new, ordered, t0, rebased=True,
                                count_storm=False)
         if self._storm_left > 0:
@@ -2240,17 +2263,27 @@ class IncrementalConsensus:
             self.storm_rebases += 1
             if self._storm_left == 0:
                 self._consec_rebases = 0   # hysteresis exit: fresh slate
-            ordered = self._rebase()
+            ordered = self._rebase_spanned()
             return self._stats(n_new, ordered, t0, rebased=True,
                                count_storm=False, storm=True)
         if self._needs_rebase_pre():
-            ordered = self._rebase()
+            ordered = self._rebase_spanned()
             return self._stats(n_new, ordered, t0, rebased=True)
         ordered, need_rebase = self._extend_pass(n_new)
         if need_rebase:
-            ordered = self._rebase()
+            ordered = self._rebase_spanned()
             return self._stats(n_new, ordered, t0, rebased=True)
         return self._stats(n_new, ordered, t0, rebased=False)
+
+    def _rebase_spanned(self) -> List[int]:
+        """:meth:`_rebase` as the pass's ``swirld.rebase`` phase; ``kind``
+        is ``widen`` where the streaming driver re-admitted archived rows,
+        else ``full``."""
+        with obs.span("swirld.rebase") as sp:
+            self._rebase_kind = "full"
+            ordered = self._rebase()
+            sp.args["kind"] = self._rebase_kind
+        return ordered
 
     def result(self) -> ConsensusResult:
         """Cumulative consensus state — bit-identical to a cold
@@ -2355,8 +2388,6 @@ class IncrementalConsensus:
                 self._consensus_round - 1,
             )
         self._latency_phase = self._latency_phase_default
-        if o is not None and o.profiler is not None:
-            o.profiler.end_chunk(n_events=int(n_new))
         return {
             "new_events": int(n_new),
             "ordered": ordered,
@@ -2612,8 +2643,8 @@ class IncrementalConsensus:
             start = np.int32(w0 + ci * chunk)
             span_len = k * chunk
             for _attempt in range(span_len + 1):
-                out = obs.stage_call_fused(
-                    "pipeline.rounds_span_stage", k, rounds_span_stage,
+                out = obs.stage_call(
+                    "pipeline.rounds_span_stage", rounds_span_stage,
                     parents_d, self._ssm_d, jnp.asarray(self._colpos_w),
                     creator_d, stake_d, np.int32(n_valid),
                     jnp.asarray(carry_h[0]), jnp.asarray(carry_h[1]),
@@ -2623,13 +2654,16 @@ class IncrementalConsensus:
                     s_max=self._s_cap, has_forks=has_forks,
                     chunk=chunk, k_chunks=k,
                 )
+                obs.tally("rounds_probes")
                 tab = obs.to_host(out[2])
                 registered = np.unique(tab[tab >= 0])
                 missing = registered[self._colpos_w[registered] < 0]
                 if missing.size == 0:
                     state = out
+                    obs.tally("rounds_units")
                     break
                 self._add_columns([int(e) for e in missing])
+                obs.tally("columns_added", len(missing))
             else:
                 raise RuntimeError("witness-column span did not converge")
             if int(obs.to_host(state[4])):
@@ -2651,53 +2685,93 @@ class IncrementalConsensus:
     def _extend_pass(self, n_new: int) -> Tuple[List[int], bool]:
         """One incremental pass over the ``n_new`` freshly packed events.
         Returns ``(newly_ordered, need_rebase)``."""
-        p = self.packer
-        lo = self._lo
-        w0 = self._n_done - lo
-        n1 = len(p)
-        chunk = self._chunk
-        n_pad_new = _bucket(n_new, chunk)
-        self._ensure_row_capacity(w0 + n_pad_new)
-        sl = slice(w0, w0 + n_new)
-        gsl = slice(self._n_done, n1)
-        par, creator_new, coin_new, t_new = p.window_view(self._n_done, n1)
-        parw = np.where(par >= 0, par - lo, -1).astype(np.int32)
-        self._parents_w[sl] = parw
-        self._creator_w[sl] = creator_new
-        self._coin_w[sl] = coin_new
-        self._t_w[sl] = t_new
-        for j in range(n_new):
-            sp = parw[j, 0]
-            self._depth_w[w0 + j] = 1 + (self._depth_w[sp] if sp >= 0 else 0)
-        dmax = int(self._depth_w[: w0 + n_new].max(initial=1))
-        if dmax > self._chain_cap:
-            self._chain_cap = _bucket(dmax, 32)
-        # member-table slots for the new events (host bookkeeping only —
-        # the ssm block kernel gathers straight from the sees slab)
-        for j in range(n_new):
-            m = int(creator_new[j])
-            slot = int(self._mcount[m])
-            if slot >= self._k_cap:
-                self._grow_k(slot + 1)
-            self._mt_np[m, slot] = w0 + j
-            self._mcount[m] = slot + 1
-        # fork pairs arriving with this delta (window-remapped)
-        if p.n_fork_pairs > self._g_done:
-            fp = p.fork_pairs_view(self._g_done)
-            new_pairs = np.stack(
-                [fp[:, 0], fp[:, 1] - lo, fp[:, 2] - lo], axis=1,
-            ).astype(np.int32)
-            was_forkless = self._fork_np.shape[0] == 0
-            self._fork_np = np.concatenate([self._fork_np, new_pairs])
-            self._g_done = p.n_fork_pairs
-            if was_forkless:
-                self._materialize_sees()
-        has_forks = self._fork_np.shape[0] > 0
+        with obs.span("swirld.plan"):
+            p = self.packer
+            lo = self._lo
+            w0 = self._n_done - lo
+            n1 = len(p)
+            chunk = self._chunk
+            n_pad_new = _bucket(n_new, chunk)
+            self._ensure_row_capacity(w0 + n_pad_new)
+            sl = slice(w0, w0 + n_new)
+            gsl = slice(self._n_done, n1)
+            par, creator_new, coin_new, t_new = p.window_view(
+                self._n_done, n1)
+            parw = np.where(par >= 0, par - lo, -1).astype(np.int32)
+            self._parents_w[sl] = parw
+            self._creator_w[sl] = creator_new
+            self._coin_w[sl] = coin_new
+            self._t_w[sl] = t_new
+            for j in range(n_new):
+                sp = parw[j, 0]
+                self._depth_w[w0 + j] = 1 + (
+                    self._depth_w[sp] if sp >= 0 else 0)
+            dmax = int(self._depth_w[: w0 + n_new].max(initial=1))
+            if dmax > self._chain_cap:
+                self._chain_cap = _bucket(dmax, 32)
+            # member-table slots for the new events (host bookkeeping only —
+            # the ssm block kernel gathers straight from the sees slab)
+            for j in range(n_new):
+                m = int(creator_new[j])
+                slot = int(self._mcount[m])
+                if slot >= self._k_cap:
+                    self._grow_k(slot + 1)
+                self._mt_np[m, slot] = w0 + j
+                self._mcount[m] = slot + 1
+            # fork pairs arriving with this delta (window-remapped)
+            if p.n_fork_pairs > self._g_done:
+                fp = p.fork_pairs_view(self._g_done)
+                new_pairs = np.stack(
+                    [fp[:, 0], fp[:, 1] - lo, fp[:, 2] - lo], axis=1,
+                ).astype(np.int32)
+                was_forkless = self._fork_np.shape[0] == 0
+                self._fork_np = np.concatenate([self._fork_np, new_pairs])
+                self._g_done = p.n_fork_pairs
+                if was_forkless:
+                    self._materialize_sees()
+            has_forks = self._fork_np.shape[0] > 0
 
-        parents_d = jnp.asarray(self._parents_w)
-        creator_d = jnp.asarray(self._creator_w)
-        stake_d = jnp.asarray(self._stake)
-        n_valid = np.int32(w0 + n_new)
+            parents_d = jnp.asarray(self._parents_w)
+            creator_d = jnp.asarray(self._creator_w)
+            stake_d = jnp.asarray(self._stake)
+            n_valid = np.int32(w0 + n_new)
+            mt_d = jnp.asarray(self._mt_np)
+            # round-restricted column suffix: a new row i is only ever
+            # queried against witness columns of round >= r0(i) - 1 — the
+            # rounds scan asks for round == r0(i) and fame collects votes
+            # from the single round below the voter — so columns whose
+            # witness round sits entirely below min_i r0(i) - 1 can skip
+            # the extension matmul; their block entries keep the slab
+            # value (zero), which no reader ever queries for these rows.
+            col_lo = 0
+            if self._n_cols and n_new:
+                lb = np.zeros((n_new,), np.int32)
+                rw = self._rnd_w
+                for j in range(n_new):
+                    p0, p1 = int(parw[j, 0]), int(parw[j, 1])
+                    b = 0
+                    if p0 >= 0:
+                        b = int(rw[p0]) if p0 < w0 else int(lb[p0 - w0])
+                    if p1 >= 0:
+                        b2 = int(rw[p1]) if p1 < w0 else int(lb[p1 - w0])
+                        if b2 > b:
+                            b = b2
+                    lb[j] = b
+                min_lb = int(lb.min())
+                if min_lb > 1:
+                    ce = self._col_events[: self._n_cols]
+                    qm = rw[np.clip(ce, 0, self._w_pad - 1)] >= min_lb - 1
+                    first = (int(np.argmax(qm)) if qm.any()
+                             else self._n_cols)
+                    # block-aligned so the shape family stays the one the
+                    # un-cut pass would compile anyway
+                    col_lo = (first // 256) * 256
+            c_eff = min(
+                self._wcol_cap - col_lo,
+                _bucket(max(self._n_cols - col_lo, 1), 256),
+            )
+            cols_d = jnp.asarray(
+                self._col_events[col_lo : col_lo + c_eff])
 
         # ---- device: one fused dispatch extends ancestry + sees, then one
         # ssm block call covers every new row x every live column (the
@@ -2722,41 +2796,6 @@ class IncrementalConsensus:
                 block=self._block, matmul_dtype_name=self._mm,
             )
             self._sees_d = self._anc_d
-        mt_d = jnp.asarray(self._mt_np)
-        # round-restricted column suffix: a new row i is only ever queried
-        # against witness columns of round >= r0(i) - 1 — the rounds scan
-        # asks for round == r0(i) and fame collects votes from the single
-        # round below the voter — so columns whose witness round sits
-        # entirely below min_i r0(i) - 1 can skip the extension matmul;
-        # their block entries keep the slab value (zero), which no reader
-        # ever queries for these rows.
-        col_lo = 0
-        if self._n_cols and n_new:
-            lb = np.zeros((n_new,), np.int32)
-            rw = self._rnd_w
-            for j in range(n_new):
-                p0, p1 = int(parw[j, 0]), int(parw[j, 1])
-                b = 0
-                if p0 >= 0:
-                    b = int(rw[p0]) if p0 < w0 else int(lb[p0 - w0])
-                if p1 >= 0:
-                    b2 = int(rw[p1]) if p1 < w0 else int(lb[p1 - w0])
-                    if b2 > b:
-                        b = b2
-                lb[j] = b
-            min_lb = int(lb.min())
-            if min_lb > 1:
-                ce = self._col_events[: self._n_cols]
-                qm = rw[np.clip(ce, 0, self._w_pad - 1)] >= min_lb - 1
-                first = int(np.argmax(qm)) if qm.any() else self._n_cols
-                # block-aligned so the shape family stays the one the
-                # un-cut pass would compile anyway
-                col_lo = (first // 256) * 256
-        c_eff = min(
-            self._wcol_cap - col_lo,
-            _bucket(max(self._n_cols - col_lo, 1), 256),
-        )
-        cols_d = jnp.asarray(self._col_events[col_lo : col_lo + c_eff])
         if self._cache_blocks:
             # gather the new rows' a-side once; the pass's witness-column
             # adds reuse it (new witnesses are always new rows)
@@ -2784,79 +2823,86 @@ class IncrementalConsensus:
         self._rows_hi = w0 + n_pad_new
 
         # ---- resumed rounds scan over the new events only
-        r_base_d = np.int32(self._r_base)
-        if self._fuse > 1:
-            state = self._rounds_span_fixpoint(
-                parents_d, creator_d, stake_d, n_valid, has_forks,
-                w0, n_pad_new, r_base_d,
-            )
-            if state is None:
-                # round/slot capacity overflow mid-span -> rebase now;
-                # the unfused path also commits nothing on overflow, so
-                # skipping the remaining spans is exact
-                return [], True
-        else:
-            state = (
-                jnp.asarray(self._rnd_w),
-                jnp.asarray(self._wits_w),
-                jnp.asarray(self._tab_np),
-                jnp.asarray(self._cnt_np),
-                jnp.zeros((), dtype=jnp.int32),
-            )
-            for start in range(w0, w0 + n_pad_new, chunk):
-                for _attempt in range(chunk + 1):
-                    out = obs.stage_call(
-                        "pipeline.rounds_chunk_stage", rounds_chunk_stage,
-                        parents_d, self._ssm_d, jnp.asarray(self._colpos_w),
-                        creator_d, stake_d, np.int32(n_valid), *state,
-                        np.int32(start), r_base_d,
-                        tot_stake=self._tot, r_max=self._r_cap,
-                        s_max=self._s_cap, has_forks=has_forks, chunk=chunk,
-                    )
-                    tab = obs.to_host(out[2])
-                    registered = np.unique(tab[tab >= 0])
-                    missing = registered[self._colpos_w[registered] < 0]
-                    if missing.size == 0:
-                        state = out
-                        break
-                    rnd_np = obs.to_host(out[0])
-                    ce = np.arange(start, start + chunk, dtype=np.int64)
-                    pc = self._parents_w[ce]
-                    r0 = np.where(
-                        pc[:, 0] < 0,
-                        -1,
-                        np.maximum(rnd_np[np.maximum(pc[:, 0], 0)],
-                                   rnd_np[np.maximum(pc[:, 1], 0)]),
-                    )
-                    affected = False
-                    for w in missing:
-                        if w < start:
-                            affected = True
+        with obs.span("swirld.rounds"):
+            r_base_d = np.int32(self._r_base)
+            if self._fuse > 1:
+                state = self._rounds_span_fixpoint(
+                    parents_d, creator_d, stake_d, n_valid, has_forks,
+                    w0, n_pad_new, r_base_d,
+                )
+                if state is None:
+                    # round/slot capacity overflow mid-span -> rebase now;
+                    # the unfused path also commits nothing on overflow, so
+                    # skipping the remaining spans is exact
+                    return [], True
+            else:
+                state = (
+                    jnp.asarray(self._rnd_w),
+                    jnp.asarray(self._wits_w),
+                    jnp.asarray(self._tab_np),
+                    jnp.asarray(self._cnt_np),
+                    jnp.zeros((), dtype=jnp.int32),
+                )
+                for start in range(w0, w0 + n_pad_new, chunk):
+                    for _attempt in range(chunk + 1):
+                        out = obs.stage_call(
+                            "pipeline.rounds_chunk_stage",
+                            rounds_chunk_stage, parents_d, self._ssm_d,
+                            jnp.asarray(self._colpos_w), creator_d, stake_d,
+                            np.int32(n_valid), *state, np.int32(start),
+                            r_base_d, tot_stake=self._tot,
+                            r_max=self._r_cap, s_max=self._s_cap,
+                            has_forks=has_forks, chunk=chunk,
+                        )
+                        obs.tally("rounds_probes")
+                        tab = obs.to_host(out[2])
+                        registered = np.unique(tab[tab >= 0])
+                        missing = registered[self._colpos_w[registered] < 0]
+                        if missing.size == 0:
+                            state = out
+                            obs.tally("rounds_units")
                             break
-                        later = ce > w
-                        if np.any(later & (r0 == rnd_np[w])):
-                            affected = True
+                        rnd_np = obs.to_host(out[0])
+                        ce = np.arange(start, start + chunk, dtype=np.int64)
+                        pc = self._parents_w[ce]
+                        r0 = np.where(
+                            pc[:, 0] < 0,
+                            -1,
+                            np.maximum(rnd_np[np.maximum(pc[:, 0], 0)],
+                                       rnd_np[np.maximum(pc[:, 1], 0)]),
+                        )
+                        affected = False
+                        for w in missing:
+                            if w < start:
+                                affected = True
+                                break
+                            later = ce > w
+                            if np.any(later & (r0 == rnd_np[w])):
+                                affected = True
+                                break
+                        self._add_columns([int(e) for e in missing])
+                        obs.tally("columns_added", len(missing))
+                        if not affected:
+                            state = out
+                            obs.tally("rounds_units")
                             break
-                    self._add_columns([int(e) for e in missing])
-                    if not affected:
-                        state = out
-                        break
-                else:
-                    raise RuntimeError(
-                        "witness-column chunk did not converge"
-                    )
+                    else:
+                        raise RuntimeError(
+                            "witness-column chunk did not converge"
+                        )
 
-        # copy=True (np.array, not asarray): device pulls are read-only
-        # views, and these mirrors are mutated in place by roll/prune
-        rnd_w = obs.to_host(state[0], copy=True)
-        wits_w = obs.to_host(state[1], copy=True)
-        tab_np = obs.to_host(state[2], copy=True)
-        cnt_np = obs.to_host(state[3], copy=True)
-        if int(obs.to_host(state[4])):
-            # round/slot capacity overflow -> rebase, which self-heals:
-            # _columns_pass grows the flagged capacity and the adopted
-            # window table inherits it (never a crash)
-            return [], True
+            # copy=True (np.array, not asarray): device pulls are read-only
+            # views, and these mirrors are mutated in place by roll/prune
+            rnd_w = obs.to_host(state[0], copy=True)
+            wits_w = obs.to_host(state[1], copy=True)
+            tab_np = obs.to_host(state[2], copy=True)
+            cnt_np = obs.to_host(state[3], copy=True)
+            if int(obs.to_host(state[4])):
+                # round/slot capacity overflow -> rebase, which self-heals:
+                # _columns_pass grows the flagged capacity and the adopted
+                # window table inherits it (never a crash)
+                return [], True
+
         # straggler guard: a witness below the frozen vote horizon could
         # change a committed tally — recompute from scratch instead
         wit_mask = wits_w[sl]
@@ -2878,99 +2924,103 @@ class IncrementalConsensus:
         self._n_done = n1
 
         # ---- fame over the retained round window
-        need = self._max_round - self._r_base + 3
-        if need > self._r_fame:
-            self._r_fame = min(self._r_cap, _bucket(need, 8))
-        famous_d, dec_d = obs.stage_call(
-            "pipeline.inc_fame", fame_window_stage,
-            self._sees_d, self._ssm_d, jnp.asarray(self._colpos_w),
-            state[2], creator_d, jnp.asarray(self._coin_w), stake_d,
-            tot_stake=self._tot, coin_period=self.config.coin_period,
-            r_max=self._r_fame, s_max=self._s_cap, has_forks=has_forks,
-            matmul_dtype_name=self._mm,
-        )
-        fam = np.full((self._r_cap, self._s_cap), -1, np.int8)
-        fam[: self._r_fame] = obs.to_host(famous_d).reshape(
-            self._r_fame, self._s_cap
-        )
-        dec = np.full((self._r_cap, self._s_cap), -1, np.int32)
-        dec[: self._r_fame] = obs.to_host(dec_d).reshape(
-            self._r_fame, self._s_cap
-        )
-        self._famous_np = fam
-        self._dec_np = dec
+        with obs.span("swirld.fame"):
+            need = self._max_round - self._r_base + 3
+            if need > self._r_fame:
+                self._r_fame = min(self._r_cap, _bucket(need, 8))
+            famous_d, dec_d = obs.stage_call(
+                "pipeline.inc_fame", fame_window_stage,
+                self._sees_d, self._ssm_d, jnp.asarray(self._colpos_w),
+                state[2], creator_d, jnp.asarray(self._coin_w), stake_d,
+                tot_stake=self._tot, coin_period=self.config.coin_period,
+                r_max=self._r_fame, s_max=self._s_cap, has_forks=has_forks,
+                matmul_dtype_name=self._mm,
+            )
+            fam = np.full((self._r_cap, self._s_cap), -1, np.int8)
+            fam[: self._r_fame] = obs.to_host(famous_d).reshape(
+                self._r_fame, self._s_cap
+            )
+            dec = np.full((self._r_cap, self._s_cap), -1, np.int32)
+            dec[: self._r_fame] = obs.to_host(dec_d).reshape(
+                self._r_fame, self._s_cap
+            )
+            self._famous_np = fam
+            self._dec_np = dec
 
         # ---- order extraction for newly fame-complete rounds
-        k_done = self._consensus_round - self._r_base
-        ncomp = 0
-        for k in range(self._r_cap):
-            valid = self._tab_np[k] >= 0
-            if self._cnt_np[k] <= 0:
-                break
-            if self._max_round < self._r_base + k + 2:
-                break
-            if (fam[k][valid] < 0).any():
-                break
-            ncomp = k + 1
-        ordered_new: List[int] = []
-        if ncomp > k_done:
-            if ncomp > self._r_ord:
-                self._r_ord = min(self._r_cap, _bucket(ncomp, 2))
-            # the scan masks rounds past the fame-complete prefix, so its
-            # cost window only needs to reach ncomp — not the historical
-            # high-water mark (which still bounds the bucket family)
-            r_ord_eff = min(self._r_ord, max(2, _bucket(ncomp, 2)))
-            ts_unique, t_rank = np.unique(self._t_w, return_inverse=True)
-            t_rank = t_rank.astype(np.int32).reshape(self._t_w.shape)
-            rr_d, ts_d, recv_d = obs.stage_call(
-                "pipeline.inc_order", order_window_stage,
-                self._anc_d, state[2], state[3],
-                jnp.asarray(fam.reshape(-1)), creator_d, parents_d[:, 0],
-                jnp.asarray(t_rank),
-                np.int32(self._max_round - self._r_base),
-                np.int32(n_valid), jnp.asarray(self._recv_w),
-                r_max=r_ord_eff, s_max=self._s_cap,
-                chain=self._chain_cap,
-            )
-            rr_np = obs.to_host(rr_d)
-            tsr_np = obs.to_host(ts_d)
-            recv_np = obs.to_host(recv_d, copy=True)
-            max_dec = self._frozen_vote_hi
-            for k in range(k_done, ncomp):
-                slots = self._tab_np[k]
-                fam_events: List[int] = []
-                for s in range(self._s_cap):
-                    e = int(slots[s])
-                    if e < 0:
-                        continue
-                    is_f = int(fam[k, s]) == 1
-                    self._famous_committed[lo + e] = is_f
-                    if is_f:
-                        fam_events.append(e)
-                    max_dec = max(max_dec, self._r_base + int(dec[k, s]))
-                ufw = _unique_famous(fam_events, self._creator_w)
-                whiten = _whiten_sigs(p.sig(lo + e) for e in ufw)
-                entries = []
-                for w in np.where(rr_np == k)[0]:
-                    gi = lo + int(w)
-                    cts = int(ts_unique[tsr_np[w]])
-                    tie = crypto.hash_bytes(whiten + p.event_id(gi))
-                    entries.append((cts, tie, gi))
-                entries.sort(key=lambda x: (x[0], x[1]))
-                for cts, _tie, gi in entries:
-                    self._rr_g[gi] = self._r_base + k
-                    self._cts_g[gi] = cts
-                    self._order.append(gi)
-                    ordered_new.append(gi)
-            self._frozen_vote_hi = max_dec
-            self._consensus_round = self._r_base + ncomp
-            self._recv_w = recv_np
+        with obs.span("swirld.order") as osp:
+            k_done = self._consensus_round - self._r_base
+            ncomp = 0
+            for k in range(self._r_cap):
+                valid = self._tab_np[k] >= 0
+                if self._cnt_np[k] <= 0:
+                    break
+                if self._max_round < self._r_base + k + 2:
+                    break
+                if (fam[k][valid] < 0).any():
+                    break
+                ncomp = k + 1
+            ordered_new: List[int] = []
+            if ncomp > k_done:
+                if ncomp > self._r_ord:
+                    self._r_ord = min(self._r_cap, _bucket(ncomp, 2))
+                # the scan masks rounds past the fame-complete prefix, so its
+                # cost window only needs to reach ncomp — not the historical
+                # high-water mark (which still bounds the bucket family)
+                r_ord_eff = min(self._r_ord, max(2, _bucket(ncomp, 2)))
+                ts_unique, t_rank = np.unique(self._t_w, return_inverse=True)
+                t_rank = t_rank.astype(np.int32).reshape(self._t_w.shape)
+                rr_d, ts_d, recv_d = obs.stage_call(
+                    "pipeline.inc_order", order_window_stage,
+                    self._anc_d, state[2], state[3],
+                    jnp.asarray(fam.reshape(-1)), creator_d, parents_d[:, 0],
+                    jnp.asarray(t_rank),
+                    np.int32(self._max_round - self._r_base),
+                    np.int32(n_valid), jnp.asarray(self._recv_w),
+                    r_max=r_ord_eff, s_max=self._s_cap,
+                    chain=self._chain_cap,
+                )
+                rr_np = obs.to_host(rr_d)
+                tsr_np = obs.to_host(ts_d)
+                recv_np = obs.to_host(recv_d, copy=True)
+                max_dec = self._frozen_vote_hi
+                for k in range(k_done, ncomp):
+                    slots = self._tab_np[k]
+                    fam_events: List[int] = []
+                    for s in range(self._s_cap):
+                        e = int(slots[s])
+                        if e < 0:
+                            continue
+                        is_f = int(fam[k, s]) == 1
+                        self._famous_committed[lo + e] = is_f
+                        if is_f:
+                            fam_events.append(e)
+                        max_dec = max(max_dec, self._r_base + int(dec[k, s]))
+                    ufw = _unique_famous(fam_events, self._creator_w)
+                    whiten = _whiten_sigs(p.sig(lo + e) for e in ufw)
+                    entries = []
+                    for w in np.where(rr_np == k)[0]:
+                        gi = lo + int(w)
+                        cts = int(ts_unique[tsr_np[w]])
+                        tie = crypto.hash_bytes(whiten + p.event_id(gi))
+                        entries.append((cts, tie, gi))
+                    entries.sort(key=lambda x: (x[0], x[1]))
+                    for cts, _tie, gi in entries:
+                        self._rr_g[gi] = self._r_base + k
+                        self._cts_g[gi] = cts
+                        self._order.append(gi)
+                        ordered_new.append(gi)
+                self._frozen_vote_hi = max_dec
+                self._consensus_round = self._r_base + ncomp
+                self._recv_w = recv_np
+            osp.args["ordered"] = len(ordered_new)
 
         # ---- advance the round window and prune the decided prefix
-        dr = self._consensus_round - self._r_base
-        if dr > 0:
-            self._roll_rounds(dr)
-        self._maybe_prune()
+        with obs.span("swirld.retire"):
+            dr = self._consensus_round - self._r_base
+            if dr > 0:
+                self._roll_rounds(dr)
+            self._maybe_prune()
         return ordered_new, False
 
     def _roll_rounds(self, dr: int) -> None:
@@ -3151,7 +3201,7 @@ class IncrementalConsensus:
                 },
                 registry=oo.registry if oo is not None else None,
             )
-        result = finalize_order(packed, out, ts_unique)
+        result = _finalize_spanned(packed, out, ts_unique)
 
         # ---- commit everything the batch pass decided
         self._grow_global(n)
@@ -3196,7 +3246,8 @@ class IncrementalConsensus:
             lo = min(lo, int(packed.fork_pairs[:, 1:].min()))
         self._lo = lo
         self._r_base = cr
-        self._on_rebase(packed, out, aux)
+        with obs.span("swirld.retire"):
+            self._on_rebase(packed, out, aux)
         w_used = n - lo
         self._w_pad = max(
             self._w_pad,
@@ -3244,7 +3295,7 @@ class IncrementalConsensus:
             self._dec_np[:rows, :sw] = dec[cr:hi]
         # column store: keep retained-round witness columns
         bat_pos = aux["col_pos"]
-        bat_ssm = np.asarray(aux["ssm_c"])
+        bat_ssm = obs.to_host(aux["ssm_c"])
         kept = [
             (e, int(bat_pos[e]))
             for e in range(lo, n)
@@ -3263,12 +3314,12 @@ class IncrementalConsensus:
                 self._colpos_w[e - lo] = j
         self._n_cols = n_cols
         # visibility slabs, window-sliced (sees aliases anc while fork-free)
-        bat_anc = np.asarray(aux["anc"])
+        bat_anc = obs.to_host(aux["anc"])
         anc_w = np.zeros((w_pad, w_pad), bool)
         anc_w[:w_used, :w_used] = bat_anc[lo:n, lo:n]
         self._anc_d = self._put(anc_w)
         if packed.fork_pairs.shape[0]:
-            bat_sees = np.asarray(aux["sees"])
+            bat_sees = obs.to_host(aux["sees"])
             sees_w = np.zeros((w_pad, w_pad), bool)
             sees_w[:w_used, :w_used] = bat_sees[lo:n, lo:n]
             self._sees_d = self._put(sees_w)
