@@ -10,6 +10,7 @@ file, so that one worker loads the library.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -153,6 +154,32 @@ def test_ssm_block_stage_256_members(one_chip):
         rows=rows, tot_stake=m, matmul_dtype_name="bfloat16",
     ).compile()
     assert c.memory_analysis().argument_size_in_bytes >= w * w
+    assert not _scalar_gathers(c.as_text())
+
+
+def _scalar_gathers(text):
+    return re.findall(r"gather\([^\n]*slice_sizes=\{1,1\}", text)
+
+
+def test_ssm_block_from_rows_stage_wide256_gathers_slices(one_chip):
+    """The b-side gather is two slice gathers at the 256-member window's
+    most common block shape.  An element gather lowers to a scalar
+    gather fed by an (M*K, C, 2) index array, 3.26e10 bytes accessed
+    per call at this shape."""
+    w, m, k, rows, cols = 18432, 256, 160, 1024, 512
+    c = pipeline.ssm_block_from_rows_stage.lower(
+        _sds((m, rows, k), jnp.bool_, one_chip),    # a_r3
+        _sds((w, w), jnp.bool_, one_chip),          # sees
+        _sds((m, k), jnp.int32, one_chip),          # member_table
+        _sds((m,), jnp.int32, one_chip),            # stake
+        _sds((cols,), jnp.int32, one_chip),         # cols
+        _sds((), jnp.int32, one_chip),              # row_off
+        rows=rows, tot_stake=m, matmul_dtype_name="bfloat16",
+    ).compile()
+    assert not _scalar_gathers(c.as_text())
+    cost = c.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    assert cost["bytes accessed"] < 4e9
 
 
 def test_rounds_span_stage_256_members(one_chip):

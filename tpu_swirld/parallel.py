@@ -51,7 +51,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from tpu_swirld import obs
 from tpu_swirld.store.slab import SlabStore
 from tpu_swirld.store.streaming import StreamingConsensus
-from tpu_swirld.tpu.pipeline import _bmm, consensus_body
+from tpu_swirld.tpu.pipeline import _bmm, consensus_body, member_cols_block
 
 MEMBER_AXIS = "members"
 
@@ -202,17 +202,15 @@ def make_ssm_block_fn_for_mesh(mesh: Mesh):
                 idx = mtl.reshape(-1)
                 valid = idx >= 0
                 idxc = jnp.clip(idx, 0, n - 1)
-                colsc = jnp.clip(colsl, 0, n - 1)
                 cv = colsl >= 0
                 s_rows = lax.dynamic_slice(s, (row0l, 0), (rows, n))
                 a_r3 = (
                     (s_rows[:, idxc] & valid[None, :])
                     .reshape(rows, ml, k).transpose(1, 0, 2)
                 )
-                b_cols = (
-                    s[idxc[:, None], colsc[None, :]]
-                    & valid[:, None] & cv[None, :]
-                ).reshape(ml, k, colsl.shape[0])
+                b_cols = member_cols_block(s, idxc, valid, colsl).reshape(
+                    ml, k, colsl.shape[0]
+                )
 
                 def body(mm, acc):
                     hit = _bmm(a_r3[mm], b_cols[mm], dtype)

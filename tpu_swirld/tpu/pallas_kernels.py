@@ -50,6 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpu_swirld import obs
+from tpu_swirld.tpu.pipeline import member_cols_block
 
 
 @functools.lru_cache(maxsize=None)
@@ -221,17 +222,15 @@ def ssm_block_pallas(sees, member_table, stake, cols, row0, *, rows,
     idx = member_table.reshape(-1)
     valid = idx >= 0
     idxc = jnp.clip(idx, 0, n - 1)
-    colsc = jnp.clip(cols, 0, n - 1)
     col_valid = cols >= 0
     sees_rows = jax.lax.dynamic_slice(sees, (row0, 0), (rows, n))
     a = (
         (sees_rows[:, idxc] & valid[None, :])
         .reshape(rows, n_members, k)
     )                                                           # rows, M, K
-    b_cols = (
-        sees[idxc[:, None], colsc[None, :]]
-        & valid[:, None] & col_valid[None, :]
-    ).reshape(n_members, k, c)                                  # M, K, C
+    b_cols = member_cols_block(sees, idxc, valid, cols).reshape(
+        n_members, k, c
+    )                                                           # M, K, C
     if k_pad != k:
         a = jnp.pad(a, ((0, 0), (0, 0), (0, k_pad - k)))
         b_cols = jnp.pad(b_cols, ((0, 0), (0, k_pad - k), (0, 0)))
