@@ -34,8 +34,7 @@ def control_readings(cell, seed: int, seconds: float):
     n = int(cfg["history_events"])
     if mix["driver"] == "open_loop":
         n = drivers.stream_syncs(mix, seconds) * int(mix["sync_events"])
-    hist = gossip.generate(cfg["members"], n, seed, cfg["stake"],
-                           int(mix["dag_seed"]))
+    hist = gossip.from_config(cfg, n, seed, int(mix["dag_seed"]))
     period = int(cfg["coin_period"])
     ref = reference.consensus(hist, period)
     ctl = reference.consensus(hist, period, num=1, den=2)

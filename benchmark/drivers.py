@@ -81,10 +81,8 @@ class ReplayDriver:
         self.engine = mix["engine"]
         self.sync = int(mix.get("sync_events", 0))
         with span("generate"):
-            self.hist = gossip.generate(
-                cfg["members"], int(cfg["history_events"]), seed,
-                cfg["stake"], int(mix["dag_seed"]),
-            )
+            self.hist = gossip.from_config(
+                cfg, int(cfg["history_events"]), seed, int(mix["dag_seed"]))
             self.events = gossip.program_events(self.hist)
         self.replays: List[Replay] = []
         self.counters: Dict = {}
@@ -179,9 +177,9 @@ class OpenLoopDriver:
         self.rate = float(mix["rate_syncs_per_s"])
         self.warm_syncs = int(mix["warmup_syncs"])
         with span("generate"):
-            self.hist = gossip.generate(
-                cfg["members"], stream_syncs(mix, seconds) * self.sync,
-                seed, cfg["stake"], int(mix["dag_seed"]))
+            self.hist = gossip.from_config(
+                cfg, stream_syncs(mix, seconds) * self.sync, seed,
+                int(mix["dag_seed"]))
             self.events = gossip.program_events(self.hist)
         self.pos = 0                     # events ingested so far
         self.emitted: List[int] = []     # order as the calls returned it

@@ -1,11 +1,12 @@
 """The benchmark's own gossip-DAG generator.
 
 A copy of the shape of ``tpu_swirld.sim.generate_gossip_dag`` /
-``stream_gossip_dag`` (per-member self-chains stitched by a random other
-parent taken from a random member's head), kept here so that no program
-change can move the yardstick.  It builds plain columns (creator, parent
-indices, timestamps, ids, signatures) that the reference reads, and the
-program's own ``Event`` records only where the engine is fed.
+``stream_gossip_dag`` (per-member self-chains, or branches where a member
+forks, stitched by a random other parent taken from a random member's
+branch head), kept here so that no program change can move the
+yardstick.  It builds plain columns (creator, parent indices,
+timestamps, ids, signatures) that the reference reads, and the program's
+own ``Event`` records only where the engine is fed.
 
 Event bytes follow the program's wire layout (``Event.body``): one parent
 count byte, the parent ids, ``<q`` timestamp, ``<I``-prefixed creator key
@@ -65,16 +66,25 @@ class History:
 
 
 def generate(n_members: int, n_events: int, seed: int, stake,
-             dag_seed: int) -> History:
-    """An honest random-gossip history of ``n_events`` events.
+             dag_seed: int, forkers: int = 0,
+             fork_prob: float = 0.05) -> History:
+    """A random-gossip history of ``n_events`` events.
 
     Genesis events first, one per member; then each event picks a random
-    creator, a random other member and that member's head as other parent
-    (the RNG call pattern of the program's generator with no forkers).
+    creator, a random other member, a random branch head of that member
+    as other parent and a random branch head of its creator to extend:
+    the RNG call pattern of the program's generator.  A member keeps one
+    branch unless it forks.  The first ``forkers`` members (before the
+    relabelling) fork: when such a member's chosen head is not its
+    genesis, with probability ``fork_prob`` the new event is a sibling of
+    that head (the same self-parent, payload ``fork:<index>``) and opens a
+    new branch.  With no forkers no ``random()`` is drawn, so the history
+    is the honest one draw for draw.
 
-    The shape of the DAG is drawn from ``dag_seed``; ``seed`` relabels the
-    members (a permutation) and gives their keys, so every seed gets the
-    same amount of work.  ``stake`` None is one each."""
+    The shape of the DAG, forks included, is drawn from ``dag_seed``;
+    ``seed`` relabels the members (a permutation) and gives their keys, so
+    every seed gets the same amount of work.  ``stake`` None is one
+    each."""
     rng = random.Random(dag_seed)
     label = list(range(n_members))
     random.Random(seed).shuffle(label)
@@ -90,7 +100,7 @@ def generate(n_members: int, n_events: int, seed: int, stake,
     payload: List[bytes] = []
     ids: List[bytes] = []
     sigs: List[bytes] = []
-    head = [0] * n_members
+    branches: List[List[int]] = []       # pre-label member -> branch heads
 
     def emit(i, c, d, parents):
         pk = members[c]
@@ -104,24 +114,35 @@ def generate(n_members: int, n_events: int, seed: int, stake,
         payload.append(d)
         creator[i] = c
         t[i] = i + 1
-        head[c] = i
 
     for c in range(min(n_members, n_events)):
         emit(c, label[c], b"", ())
+        branches.append([c])
     for i in range(n_members, n_events):
         c = rng.randrange(n_members)
         p = rng.randrange(n_members - 1)
         if p >= c:
             p += 1
-        c, p = label[c], label[p]
-        # one branch per honest member: the branch draws of the program's
-        # generator always return its single head
-        other = head[p]
-        rng.randrange(1)
-        rng.randrange(1)
-        sp[i], op[i] = head[c], other
-        emit(i, c, b"tx:%d" % i, (ids[head[c]], ids[other]))
+        other = branches[p][rng.randrange(len(branches[p]))]
+        b = rng.randrange(len(branches[c]))
+        head = branches[c][b]
+        if c < forkers and sp[head] >= 0 and rng.random() < fork_prob:
+            sp[i], op[i] = sp[head], other
+            emit(i, label[c], b"fork:%d" % i, (ids[sp[head]], ids[other]))
+            branches[c].append(i)
+        else:
+            sp[i], op[i] = head, other
+            emit(i, label[c], b"tx:%d" % i, (ids[head], ids[other]))
+            branches[c][b] = i
     return History(members, stake, creator, sp, op, t, payload, ids, sigs)
+
+
+def from_config(cfg, n_events: int, seed: int, dag_seed: int) -> History:
+    """The history of a configuration file: its members, stake and
+    forkers (``forkers`` 0 and ``fork_prob`` 0.05 where it states none)."""
+    return generate(int(cfg["members"]), n_events, seed, cfg["stake"],
+                    dag_seed, int(cfg.get("forkers", 0)),
+                    float(cfg.get("fork_prob", 0.05)))
 
 
 def program_events(hist: History, start: int = 0, stop=None):

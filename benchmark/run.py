@@ -134,8 +134,11 @@ def main(argv=None, *, root: str = spec.ROOT, devices_fn=None,
     log(f"[device] peak_bytes_in_use={peak}")
 
     drv.release()
+    t_check = time.perf_counter()
     checks = drv.checks(functools.partial(
         reference.consensus, coin_period=int(cell.config["coin_period"])))
+    log(f"[check] reference and comparison took "
+        f"{time.perf_counter() - t_check} s")
     device = {
         "platform": devices[0].platform, "kind": devices[0].device_kind,
         "count": len(jax.devices()), "memory_peak_bytes": int(peak),

@@ -86,6 +86,22 @@ def load_cell(name: str, root: str = ROOT,
     )
 
 
+def fork_faults(cfg: Dict) -> List[str]:
+    """Where a configuration's forkers break its own BFT guarantee.  The
+    run's seed relabels the members, so any ``forkers`` of them may be
+    the ones that fork: their stake is taken as the largest it can be."""
+    members, stake = int(cfg["members"]), sorted(cfg["stake"], reverse=True)
+    f = cfg.get("forkers", 0)
+    if isinstance(f, bool) or not isinstance(f, int) or not 0 <= f < members:
+        return [f"forkers {f!r} is not a count under members {members}"]
+    if 3 * sum(stake[:f]) >= sum(stake):
+        return [f"forkers {f} may hold a third of the stake or more"]
+    p = cfg.get("fork_prob", 0.05)
+    if f and not (isinstance(p, (int, float)) and 0 < p < 1):
+        return [f"fork_prob {p!r} is not in (0, 1)"]
+    return []
+
+
 def validate(root: str = ROOT, bench: Optional[Dict] = None) -> List[str]:
     """Every fault found in the benchmark's files (empty when sound)."""
     bench = bench if bench is not None else load_benchmark(root)
@@ -101,8 +117,11 @@ def validate(root: str = ROOT, bench: Optional[Dict] = None) -> List[str]:
     for c in bench["configs"]:
         if not os.path.isfile(os.path.join(root, c["file"])):
             bad.append(f"config {c['name']}: no file {c['file']}")
-        elif set(c["reduced"]) - set(_load_json(root, c["file"])):
+            continue
+        cfg = _load_json(root, c["file"])
+        if set(c["reduced"]) - set(cfg):
             bad.append(f"config {c['name']}: reduced key not in its file")
+        bad += [f"config {c['name']}: {f}" for f in fork_faults(cfg)]
     for m in bench["end_to_end"] + bench["per_layer"]:
         if not UNIT.match(m["unit"]) or m["better"] not in ("lower", "higher"):
             bad.append(f"metric {m['name']}: bad unit or direction")

@@ -50,6 +50,18 @@ def toy_root(tmp_path_factory):
     return str(root)
 
 
+@pytest.fixture(scope="module")
+def sim_crypto():
+    """The program's sim signature scheme, which the generator's
+    signatures follow, so that the oracle accepts its events."""
+    from tpu_swirld import crypto
+
+    before = crypto.backend_name()
+    crypto.set_backend("sim")
+    yield
+    crypto.set_backend(before)
+
+
 @pytest.fixture
 def cpu_devices():
     import jax

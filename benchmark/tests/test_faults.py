@@ -5,8 +5,8 @@ The harness's look for a chip is skipped (``devices_fn``); everything
 else is the run as the driver makes it, at a toy size on the CPU.  Each
 fault that these cells can have is planted once: a step that returns its
 state unchanged, half of the work left out, and an answer altered where
-it is produced.  There is no exchange between chips to leave out: every
-cell runs on one chip.
+it is produced, on an honest history and on a forked one.  There is no
+exchange between chips to leave out: every cell runs on one chip.
 """
 
 import contextlib
@@ -73,7 +73,8 @@ def result_line(toy_root, cpu_devices, cell, program_fn=drivers.Program):
     return line
 
 
-@pytest.mark.parametrize("cell", ["toy8.catchup", "toy8.stream", "toy8.live"])
+@pytest.mark.parametrize("cell", ["toy8.catchup", "toy8.stream", "toy8.live",
+                                  "toy8f2.catchup", "toy8f2.stream"])
 def test_sound_run_is_correct(toy_root, cpu_devices, cell):
     line = result_line(toy_root, cpu_devices, cell)
     assert line["correct"] is True and line["failed"] == 0
@@ -87,6 +88,9 @@ def test_sound_run_is_correct(toy_root, cpu_devices, cell):
     ("toy8.stream", "unchanged"), ("toy8.stream", "half"),
     ("toy8.stream", "altered"),
     ("toy8.live", "unchanged"), ("toy8.live", "altered"),
+    ("toy8f2.catchup", "half"), ("toy8f2.catchup", "altered"),
+    ("toy8f2.stream", "unchanged"), ("toy8f2.stream", "half"),
+    ("toy8f2.stream", "altered"),
 ])
 def test_fault_reads_incorrect(toy_root, cpu_devices, cell, fault):
     line = result_line(toy_root, cpu_devices, cell, broken(fault))

@@ -66,8 +66,9 @@ def test_idle_split_by_innermost_span_adds_up_to_idle(run):
         prog.log["window_minus_busy_s"])
 
 
-def test_readers(run):
+def test_readers(run, monkeypatch):
     ctx, rec = ctx_of(run, passes=[500.0, 250.0])
+    monkeypatch.setattr(program, "recorder", lambda: rec)
     ctx._program = program._align(ctx.trace, rec)
     # host phases 0.12 s of idle over the 2 calls of the steady window
     assert reader("host_idle_ms_per_call.catchup")(ctx) == pytest.approx(
@@ -91,7 +92,8 @@ def test_stage_programs_dispatched_against_traced(run):
 
 @pytest.mark.parametrize("fault", ["count", "spread", "dropped", "absent"])
 def test_nothing_is_read_where_the_run_cannot_be_aligned(run, fault,
-                                                         capsys):
+                                                         capsys,
+                                                         monkeypatch):
     run = copy.deepcopy(run)
     events = run["recorder"]["events"]
     if fault == "count":
@@ -108,10 +110,43 @@ def test_nothing_is_read_where_the_run_cannot_be_aligned(run, fault,
     if fault == "absent":
         rec = None
         ctx._program = program._align(ctx.trace, rec)
+    monkeypatch.setattr(program, "recorder", lambda: rec)
     assert program.read(ctx, rec) is None
-    for m in ("host_ms_p50.live", "host_idle_ms_per_call.catchup",
-              "rounds_probes_per_span.catchup"):
+    for m in ("host_ms_p50.live", "host_idle_ms_per_call.catchup"):
         assert reader(m)(ctx) is None
+    log = capsys.readouterr().err
+    assert "[program]" in log
+    if fault != "absent":
+        assert f'"unaligned": "{fault}"' in log
+
+
+@pytest.mark.parametrize("fault", ["count", "spread", "dropped", "absent"])
+def test_counters_are_read_without_the_alignment(run, fault, monkeypatch,
+                                                 capsys):
+    """Each call's record holds its own counts: the probes per span read
+    the same whether or not the spans align, and nothing without a
+    recorder."""
+    run = copy.deepcopy(run)
+    events = run["recorder"]["events"]
+    if fault == "count":
+        # a call's outermost span missing: its pass record still counts
+        run["recorder"]["events"] = [
+            e for e in events if not (e["name"] == "swirld.stream_ingest"
+                                      and e["ts"] > 1e6)]
+    elif fault == "spread":
+        last = max((e for e in events if e["name"] ==
+                    "swirld.stream_ingest"), key=lambda e: e["ts"])
+        last["dur"] -= 2000.0
+    elif fault == "dropped":
+        run["recorder"]["dropped"] = 10
+    ctx, rec = ctx_of(run)
+    if fault == "absent":
+        rec = None
+    monkeypatch.setattr(program, "recorder", lambda: rec)
+    want = None if fault == "absent" else pytest.approx(4 / 3)
+    for m in ("rounds_probes_per_span.catchup",
+              "rounds_probes_per_span.live"):
+        assert reader(m)(ctx) == want
     assert "[program]" in capsys.readouterr().err
 
 

@@ -2,6 +2,9 @@
 
 import json
 import os
+import shutil
+
+import pytest
 
 from benchmark import spec
 
@@ -50,3 +53,41 @@ def test_config_files_hold_their_reduced_keys():
         assert cfg["reduced"] == c["reduced"]
         assert cfg["source"] == c["source"]
         assert len(cfg["stake"]) == cfg["members"]
+
+
+@pytest.mark.parametrize("forkers,stake,fork_prob,fault", [
+    (0, [1] * 4, None, None),
+    (1, [1] * 4, 0.3, None),
+    (21, [1] * 64, 0.05, None),
+    (22, [1] * 64, 0.05, "third"),        # 66 of 64 * 3
+    (1, [1, 1, 1, 3], 0.3, "third"),      # any member may be the forker
+    (1, [1] * 3, 0.3, "third"),
+    (-1, [1] * 4, None, "count"),
+    (4, [1] * 4, None, "count"),
+    (1, [1] * 4, 1.0, "fork_prob"),
+    (1, [1] * 4, 0, "fork_prob"),
+])
+def test_forkers_must_stay_under_a_third_of_the_stake(forkers, stake,
+                                                      fork_prob, fault):
+    cfg = {"members": len(stake), "stake": stake, "forkers": forkers}
+    if fork_prob is not None:
+        cfg["fork_prob"] = fork_prob
+    faults = spec.fork_faults(cfg)
+    if fault is None:
+        assert faults == []
+    else:
+        assert len(faults) == 1 and {
+            "third": "a third", "count": "not a count",
+            "fork_prob": "fork_prob"}[fault] in faults[0]
+
+
+def test_validate_refuses_a_forked_config_past_its_guarantee(toy_root,
+                                                           tmp_path):
+    root = tmp_path / "checkout"
+    shutil.copytree(toy_root, root)
+    path = root / "benchmark" / "configs" / "toy8f2.json"
+    cfg = json.loads(path.read_text())
+    cfg["forkers"] = 3                              # 9 of 8 * 3
+    path.write_text(json.dumps(cfg))
+    assert any(f.startswith("config toy8f2: forkers 3")
+               for f in spec.validate(str(root)))

@@ -15,12 +15,18 @@ and the benchmark's span closes as the engine call returns.
 It reads nothing (None) where the program keeps no such recorder (a
 checkout from before it), where the counts differ, where the ends spread
 by more than :data:`MAX_SPREAD_S`, or where the recorder dropped events.
-Every call logs one ``[program]`` line on standard error: the offset and
-its spread, the device-idle seconds of the steady window split by the
-innermost program span (``outside`` where none is open) against
-``window_s - busy_s``, and, over the calls of the steady window, the
-``*_stage`` programs the program dispatched against the ``*_stage``
-modules the trace holds.
+Every call logs one ``[program]`` line on standard error: why the spans
+could not be aligned (``unaligned``: ``dropped``, ``count`` or
+``spread``), or else the offset and its spread, the device-idle seconds
+of the steady window split by the innermost program span (``outside``
+where none is open) against ``window_s - busy_s``, and, over the calls of
+the steady window, the ``*_stage`` programs the program dispatched
+against the ``*_stage`` modules the trace holds.
+
+The counters need none of that: :func:`counted` reads each engine call's
+record as it is, with no pairing and no complete recorder, since each
+record holds its own call's counts.  The recorder is fresh per profiler
+session, and the session wraps the window only.
 """
 
 from __future__ import annotations
@@ -118,6 +124,18 @@ def read(ctx, rec=None) -> Optional[Program]:
     return prog if prog.calls else None
 
 
+def counted(ctx, rec=None) -> List[Dict]:
+    """The counters of every engine call the recorder holds: the args of
+    each ``swirld.pass`` / ``swirld.batch`` record (they do not nest),
+    whether or not the spans align.  Logs the ``[program]`` line too."""
+    rec = recorder() if rec is None else rec
+    read(ctx, rec)
+    if rec is None:
+        return []
+    return [e["args"] for e in rec.events
+            if e.get("ph") == "X" and "rounds_probes" in e["args"]]
+
+
 def _align(trace, rec) -> Program:
     empty = Program([], [], 0.0, 0.0, {})
     if rec is None or trace is None:
@@ -129,12 +147,14 @@ def _align(trace, rec) -> Program:
     empty.log = {"spans": len(spans), "dropped": int(rec.dropped),
                  "calls": len(calls), "ingest_calls": len(ingest)}
     if rec.dropped or not calls or len(calls) != len(ingest):
+        empty.log["unaligned"] = "dropped" if rec.dropped else "count"
         return empty
     diffs = [e - c.end for (_, e), c in zip(ingest, calls)]
     offset = statistics.median(diffs)
     spread = max(diffs) - min(diffs)
     empty.log.update(offset_s=offset, spread_ms=1e3 * spread)
     if spread > MAX_SPREAD_S:
+        empty.log["unaligned"] = "spread"
         return empty
     for s in spans:
         s.start += offset
@@ -234,18 +254,11 @@ def host_seconds(prog: Program, call: Span) -> float:
         e - s for s, e in reduce.union(waits, call.start, call.end))
 
 
-def tallied(prog: Program) -> List[Span]:
-    """The records that carry an engine call's counters (they do not
-    nest: one ``swirld.pass`` per pass, one ``swirld.batch`` per batch
-    call)."""
-    return [s for s in prog.spans if "rounds_probes" in s.args]
-
-
-def probes_per_unit(prog: Program) -> Optional[float]:
-    """Rounds-scan dispatches per accepted chunk or fused span."""
-    recs = tallied(prog)
-    units = sum(s.args["rounds_units"] for s in recs)
-    return sum(s.args["rounds_probes"] for s in recs) / units if units else None
+def probes_per_unit(records: Sequence[Dict]) -> Optional[float]:
+    """Rounds-scan dispatches per accepted chunk or fused span, over the
+    counters of :func:`counted`."""
+    units = sum(r["rounds_units"] for r in records)
+    return sum(r["rounds_probes"] for r in records) / units if units else None
 
 
 def stage_coverage(prog: Program, trace, device: int = 0) -> Dict:
