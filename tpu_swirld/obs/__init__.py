@@ -208,10 +208,13 @@ def phase_scope(metrics, tracer, name: str):
 
 #: the counters each ``swirld.pass`` / ``swirld.batch`` record carries:
 #: stage dispatches, blocking device->host pulls, rounds-scan dispatches
-#: (probes), accepted chunks or fused spans (units), and witness columns
-#: the scan found missing and added
+#: (probes), accepted chunks or fused spans (units), witness columns the
+#: scan found missing and added, and per rounds phase the fork-pair rows
+#: it ran with, the witness slots per round it carried and the most
+#: witnesses any of its rounds holds
 TALLIES = ("dispatches", "pulls", "rounds_probes", "rounds_units",
-           "columns_added")
+           "columns_added", "fork_pairs", "rounds_slots",
+           "witness_slots_used")
 
 #: registry counters that mirror the tallies under an enabled Obs (the
 #: dispatches are stage_call's per-stage ``pipeline_stage_calls``)
@@ -220,6 +223,9 @@ _TALLY_COUNTERS = {
     "rounds_probes": "pipeline_chunk_scans_total",
     "rounds_units": "pipeline_rounds_units_total",
     "columns_added": "pipeline_scan_columns_total",
+    "fork_pairs": "pipeline_rounds_fork_pairs_total",
+    "rounds_slots": "pipeline_rounds_slots_total",
+    "witness_slots_used": "pipeline_witness_slots_used_total",
 }
 
 #: events the profiler-gated recorder keeps per session; the rest are

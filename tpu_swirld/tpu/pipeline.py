@@ -1119,14 +1119,26 @@ def prepare_inputs(
     return arrays, statics, ts_unique
 
 
-def _fame_slots(wit_count, r_tight: int, s_max: int) -> int:
+def _tally_rounds(fork_pairs: int, slots: int, used: int) -> None:
+    """Count one rounds phase into the engine call's record: the fork-pair
+    rows it ran with, the witness slots per round it carried and the most
+    witnesses any of its rounds holds, all host values the caller has."""
+    obs.tally("fork_pairs", fork_pairs)
+    obs.tally("rounds_slots", slots)
+    obs.tally("witness_slots_used", used)
+
+
+def _fame_slots(wit_count, r_tight: int, s_max: int, fork_pairs: int) -> int:
     """Witness slots per round for the fame/order stage: the most any of
     its ``r_tight`` rounds holds, bucketed.  Slots fill from 0, so the
     cut drops only empty slots.  The forked fame tally costs
     O(S^2 * members * rounds): at the worst-case capacity of config 4
-    (S = 2019) that is tens of GB, at the observed count it is small."""
-    used = int(np.max(obs.to_host(wit_count[:r_tight]), initial=1))
-    return min(s_max, _bucket(used, 8))
+    (S = 2019) that is tens of GB, at the observed count it is small.
+    The same pull gives the rounds phase's counters
+    (:func:`_tally_rounds`, ``s_max`` the slots the scan carried)."""
+    used = int(np.max(obs.to_host(wit_count[:r_tight]), initial=0))
+    _tally_rounds(fork_pairs, s_max, used)
+    return min(s_max, _bucket(max(used, 1), 8))
 
 
 def _healed_capacities(ovf: int, *, r_eff: int, r_cap: int, s_eff: int,
@@ -1287,6 +1299,8 @@ def _run_consensus(packed, config, *, block, r_max, s_max,
                 s_cap=parents.shape[0],
             )
             retries += 1
+        _tally_rounds(len(packed.fork_pairs), s_max,
+                      int(np.max(out["wit_count"], initial=0)))
         t_device = time.perf_counter() - t_dev0
         t_fin0 = time.perf_counter()
         result = _finalize_spanned(packed, out, ts_unique)
@@ -1347,7 +1361,8 @@ def _run_consensus(packed, config, *, block, r_max, s_max,
         retries += 1
     max_round = int(obs.to_host(stage_a["max_round"]))
     r_tight = min(r_rounds, _bucket(max_round + 3, 8))
-    s_tight = _fame_slots(stage_a["wit_count"], r_tight, s_max)
+    s_tight = _fame_slots(stage_a["wit_count"], r_tight, s_max,
+                          len(packed.fork_pairs))
     tab_b = stage_a["wit_table"][:r_tight, :s_tight]
     stage_b = obs.stage_call(
         "pipeline.fame_order_stage",
@@ -1556,7 +1571,7 @@ def _columns_pass(
     if r_cap is None:
         r_cap = max(int(config.max_rounds), r_rounds)
     overflow_retries = 0
-    with obs.span("swirld.rounds"):
+    with obs.span("swirld.rounds", slots=s_max, forked=has_forks) as rsp:
         while True:
             state = (
                 jnp.zeros((n_pad,), dtype=jnp.int32),
@@ -1629,12 +1644,13 @@ def _columns_pass(
                 s_cap=n_pad,
             )
             overflow_retries += 1
+            rsp.args["slots"] = s_max
     rnd_a, wits_a, tab_a, cnt_a, _overflow_a = state
     with obs.span("swirld.fame"):
         max_round_d = jnp.max(jnp.where(jnp.arange(n_pad) < n_d, rnd_a, 0))
         max_round = int(obs.to_host(max_round_d))
         r_tight = min(r_rounds, _bucket(max_round + 3, 8))
-        s_tight = _fame_slots(cnt_a, r_tight, s_max)
+        s_tight = _fame_slots(cnt_a, r_tight, s_max, len(packed.fork_pairs))
         tab_b = tab_a[:r_tight, :s_tight]
         stage_b = obs.stage_call(
             "pipeline.fame_order_cols_stage",
@@ -2833,7 +2849,8 @@ class IncrementalConsensus:
         self._rows_hi = w0 + n_pad_new
 
         # ---- resumed rounds scan over the new events only
-        with obs.span("swirld.rounds"):
+        with obs.span("swirld.rounds", slots=self._s_cap,
+                      forked=has_forks):
             r_base_d = np.int32(self._r_base)
             if self._fuse > 1:
                 state = self._rounds_span_fixpoint(
@@ -2912,6 +2929,8 @@ class IncrementalConsensus:
                 # _columns_pass grows the flagged capacity and the adopted
                 # window table inherits it (never a crash)
                 return [], True
+            _tally_rounds(self._fork_np.shape[0], self._s_cap,
+                          int(cnt_np.max(initial=0)))
 
         # straggler guard: a witness below the frozen vote horizon could
         # change a committed tally — recompute from scratch instead
