@@ -9,7 +9,8 @@ streaming engine (64-event syncs, then ``result()``) and is compared with
 ``benchmark.reference.consensus`` (its fork-aware path) stage by stage.
 Both calls run under a JAX profiler session, so their records carry the
 counters the benchmark reads: ``fork_pairs``, ``rounds_slots`` and
-``witness_slots_used``.
+``witness_slots_used``, and ``rounds_slot_grows``, the in-place slot
+grows of the forked batch scan.
 
 Two reference conventions are reached by no generated history at the
 benchmark's coin period; :data:`FORKED_COIN2` is a literal DAG that
@@ -200,6 +201,37 @@ def test_engines_match_the_reference_on_a_forked_council(engine, dag_seed,
         assert rounds[-1]["forked"]
 
 
+@pytest.mark.parametrize("s_max", [None, 2], ids=["default", "tiny"])
+def test_forked_batch_grows_witness_slots_in_place(s_max, tmp_path):
+    """The forked batch scan starts below the worst-case slot count (the
+    honest bound, or an explicit tiny one), grows in place on each slot
+    overflow and re-runs only the overflowing chunk: the same answers, the
+    same chunks and columns as a run at the worst case, plus one dropped
+    probe per grow."""
+    hist = council(1)
+    ref = reference.consensus(hist)
+    cfg = SwirldConfig(n_members=MEMBERS)
+    packed = pack_events(gossip.program_events(hist), hist.members)
+    worst = pipeline.prepare_inputs(packed, cfg)[1]["s_max"]
+    res, (rec,), rounds = profiled(
+        lambda: pipeline.run_consensus(packed, cfg, s_max=s_max),
+        tmp_path / "grown")
+    _, (base,), _ = profiled(
+        lambda: pipeline.run_consensus(packed, cfg, s_max=worst),
+        tmp_path / "worst")
+    ok, bad = agrees(res, ref, hist.n)
+    assert ok, bad
+    assert base["rounds_slot_grows"] == 0 and base["rounds_slots"] == worst
+    assert rec["rounds_slot_grows"] >= 1
+    assert rec["witness_slots_used"] <= rec["rounds_slots"] < worst
+    assert [r["slots"] for r in rounds] == [rec["rounds_slots"]]
+    # every chunk accepted once: the scan never restarted
+    assert rec["rounds_units"] == base["rounds_units"] == -(-hist.n // 128)
+    assert rec["columns_added"] == base["columns_added"]
+    assert rec["rounds_probes"] == (base["rounds_probes"]
+                                    + rec["rounds_slot_grows"])
+
+
 @pytest.mark.parametrize("engine", ["batch", "streaming"])
 def test_an_honest_history_tallies_no_fork_pairs(engine, tmp_path):
     hist = gossip.generate(8, 400, 5, None, 5)
@@ -211,6 +243,9 @@ def test_an_honest_history_tallies_no_fork_pairs(engine, tmp_path):
     assert all(r["rounds_slots"] >= r["witness_slots_used"] > 0
                for r in records)
     assert rounds and not any(r["forked"] for r in rounds)
+    # no slot overflow: every rounds phase carries the honest bound
+    assert all(r["rounds_slot_grows"] == 0 for r in records)
+    assert all(r["slots"] == 8 + 1 for r in rounds)
 
 
 def oracle(hist, config):

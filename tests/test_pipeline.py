@@ -289,7 +289,8 @@ def test_parity_with_late_straggler_witness():
 def test_overflow_selfheal_fork_storm_smax():
     """A fork-heavy DAG under an under-provisioned witness-slot capacity
     previously died with RuntimeError("witness table overflow"); the
-    self-healing retry must double s_max and finish with full parity."""
+    self-healing grow must double s_max and finish with full parity, with
+    the order and rounds of a run whose slots never overflow."""
     from tpu_swirld.oracle.node import Node
     from tpu_swirld.packing import pack_events
     from tpu_swirld.sim import generate_gossip_dag
@@ -310,6 +311,10 @@ def test_overflow_selfheal_fork_storm_smax():
     )
     assert result.timings["overflow_retries"] >= 1
     assert_parity(node, packed, result)
+    ample = run_consensus(packed, node.config, block=64, s_max=packed.n)
+    assert ample.timings["overflow_retries"] == 0
+    assert result.order == ample.order
+    assert (result.round == ample.round).all()
 
 
 def test_overflow_selfheal_round_clamp():

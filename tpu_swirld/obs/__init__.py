@@ -210,11 +210,12 @@ def phase_scope(metrics, tracer, name: str):
 #: stage dispatches, blocking device->host pulls, rounds-scan dispatches
 #: (probes), accepted chunks or fused spans (units), witness columns the
 #: scan found missing and added, and per rounds phase the fork-pair rows
-#: it ran with, the witness slots per round it carried and the most
-#: witnesses any of its rounds holds
+#: it ran with, the witness slots per round it carried, the most
+#: witnesses any of its rounds holds and the times it grew its slots in
+#: place
 TALLIES = ("dispatches", "pulls", "rounds_probes", "rounds_units",
            "columns_added", "fork_pairs", "rounds_slots",
-           "witness_slots_used")
+           "witness_slots_used", "rounds_slot_grows")
 
 #: registry counters that mirror the tallies under an enabled Obs (the
 #: dispatches are stage_call's per-stage ``pipeline_stage_calls``)
@@ -226,6 +227,7 @@ _TALLY_COUNTERS = {
     "fork_pairs": "pipeline_rounds_fork_pairs_total",
     "rounds_slots": "pipeline_rounds_slots_total",
     "witness_slots_used": "pipeline_witness_slots_used_total",
+    "rounds_slot_grows": "pipeline_rounds_slot_grows_total",
 }
 
 #: events the profiler-gated recorder keeps per session; the rest are

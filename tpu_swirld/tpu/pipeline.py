@@ -45,10 +45,12 @@ order.  That shared rule is what makes live-oracle state and batch replays
 bit-identical for EVERY history, stragglers included.
 
 Self-healing: the rounds scan reports witness-table overflow as an
-``OVF_ROUND | OVF_SLOT`` bitmask and the host orchestrators retry with the
-flagged capacity grown (``_healed_capacities``) — a fork storm or a deeper
-DAG than the chain-derived ``r_max`` clamp degrades to a slower pass,
-never a ``RuntimeError``.
+``OVF_ROUND | OVF_SLOT`` bitmask and the host orchestrators grow the
+flagged capacity (``_healed_capacities``) — a fork storm or a deeper DAG
+than the chain-derived ``r_max`` clamp degrades to a slower pass, never a
+``RuntimeError``.  The column path's chunked scan grows a slot overflow
+in place and re-runs only the overflowing chunk, so the forked batch path
+starts from the honest slot bound and carries what its rounds use.
 
 All supermajorities are exact integer tests ``3*amount > 2*total``.  The
 device stays int32-pure: int64 timestamps are dense-ranked on the host
@@ -262,7 +264,7 @@ def rounds_scan(
 
     Returns (round int32[N], is_witness bool[N], wit_table int32[r_max,
     s_max], wit_count int32[r_max], overflow int32[] — an OVF_ROUND /
-    OVF_SLOT bitmask so the orchestrator can retry with the right
+    OVF_SLOT bitmask so the orchestrator can grow the right
     capacity).  Slot order within a round is registration (= topo) order,
     as in the oracle.  (The column-restricted variant runs via
     ``rounds_chunk_stage`` / ``_make_rounds_step`` with a ``col_pos``
@@ -299,7 +301,10 @@ def _make_rounds_step(parents, ssm, creator, stake, tot_stake, n_valid,
     / OVF_SLOT bitmask: an event landing outside the window (including a
     straggler below ``r_base`` in the incremental path) sets OVF_ROUND, a
     full slot row sets OVF_SLOT; the batch orchestrators self-heal by
-    growing the flagged capacity, the incremental driver rebases.
+    growing the flagged capacity, the incremental driver rebases.  The
+    body reads ``s_max`` only as the slot bound and the gather width, and
+    a ``-1`` slot is invalid, so a table padded with ``-1`` columns scans
+    exactly as the narrower one.
     """
     n = parents.shape[0]
     n_members = stake.shape[0]
@@ -1215,6 +1220,9 @@ def run_consensus(
 
 def _run_consensus(packed, config, *, block, r_max, s_max,
                    matmul_dtype_name, mesh, use_pallas_ssm, ssm_mode):
+    # the column path's rounds scan starts from the honest bound and grows
+    # in place; an explicit s_max is where it starts
+    s_start = packed.n_members + 1 if s_max is None else s_max
     with obs.span("swirld.plan"):
         arrays, statics, ts_unique = prepare_inputs(
             packed, config, block=block, r_max=r_max, s_max=s_max,
@@ -1324,8 +1332,8 @@ def _run_consensus(packed, config, *, block, r_max, s_max,
         return _run_consensus_columns(
             packed, config, parents, creator, t_rank, coin, stake,
             member_table, ts_unique, n=n, tot=tot, block=block,
-            r_rounds=r_rounds, r_cap=r_cap, s_max=s_max, chain=chain,
-            matmul_dtype_name=matmul_dtype_name,
+            r_rounds=r_rounds, r_cap=r_cap, s_max=s_max, s_start=s_start,
+            chain=chain, matmul_dtype_name=matmul_dtype_name,
         )
     stage_a_fn = rounds_stage
     if use_pallas_ssm:
@@ -1409,7 +1417,7 @@ def _run_consensus(packed, config, *, block, r_max, s_max,
 
 def _run_consensus_columns(
     packed, config, parents, creator, t_rank, coin, stake, member_table,
-    ts_unique, *, n, tot, block, r_rounds, r_cap, s_max, chain,
+    ts_unique, *, n, tot, block, r_rounds, r_cap, s_max, s_start, chain,
     matmul_dtype_name,
 ):
     """Column-restricted strongly-sees execution (the default path) —
@@ -1419,7 +1427,8 @@ def _run_consensus_columns(
     out, aux = _columns_pass(
         packed, config, parents, creator, t_rank, coin, stake, member_table,
         n=n, tot=tot, block=block, r_rounds=r_rounds, r_cap=r_cap,
-        s_max=s_max, chain=chain, matmul_dtype_name=matmul_dtype_name,
+        s_max=s_max, s_start=s_start, chain=chain,
+        matmul_dtype_name=matmul_dtype_name,
     )
     t_device = time.perf_counter() - t_dev0
     t_fin0 = time.perf_counter()
@@ -1439,7 +1448,7 @@ def _run_consensus_columns(
 def _columns_pass(
     packed, config, parents, creator, t_rank, coin, stake, member_table,
     *, n, tot, block, r_rounds, s_max, chain, matmul_dtype_name,
-    r_cap=None, ssm_block_fn=None,
+    r_cap=None, ssm_block_fn=None, s_start=None,
 ):
     """Column-restricted strongly-sees execution core.
 
@@ -1464,6 +1473,9 @@ def _columns_pass(
     ``aux["anc"]`` (alias — see :func:`ancestry_stage`).  ``ssm_block_fn``
     overrides the strongly-sees block kernel (signature of
     :func:`ssm_block_stage`) — the mesh and Pallas backends plug in here.
+    ``s_max`` sizes the column store; the rounds scan starts with
+    ``s_start`` witness slots per round (default ``s_max``) and grows them
+    in place on a slot overflow (``aux["s_max"]`` is where it ended).
     """
     n_pad = parents.shape[0]
     has_forks = bool(len(packed.fork_pairs))
@@ -1559,24 +1571,28 @@ def _columns_pass(
     # queried that witness's round, compute the column and re-run just
     # that chunk (columns are round-independent, so the re-run is exact);
     # otherwise the chunk's outputs are already exact and the new columns
-    # only serve future chunks.  Witness-table overflow self-heals: the
-    # scan restarts with the flagged capacity grown (the column store
-    # survives retries — columns never depend on the table shape), so an
-    # under-provisioned r_max/s_max degrades to a slower pass, never a
-    # crash.
+    # only serve future chunks.  Witness-table overflow self-heals (the
+    # column store survives either way — columns never depend on the
+    # table shape), so an under-provisioned r_max/s_max degrades to a
+    # slower pass, never a crash.  A slot overflow alone grows the slots
+    # in place: the chunk's output is dropped, the carried table padded
+    # with empty slots, and the same chunk re-run from its pre-chunk
+    # state, which no overflow touched.  A round overflow restarts the
+    # scan with the window grown.
     chunk_size = min(128, n_pad)
     while n_pad % chunk_size:
         chunk_size //= 2
     parents_np = parents
     if r_cap is None:
         r_cap = max(int(config.max_rounds), r_rounds)
-    overflow_retries = 0
-    with obs.span("swirld.rounds", slots=s_max, forked=has_forks) as rsp:
+    s_scan = s_max if s_start is None else s_start
+    overflow_retries = slot_grows = 0
+    with obs.span("swirld.rounds", slots=s_scan, forked=has_forks) as rsp:
         while True:
             state = (
                 jnp.zeros((n_pad,), dtype=jnp.int32),
                 jnp.zeros((n_pad,), dtype=bool),
-                jnp.full((r_rounds, s_max), -1, dtype=jnp.int32),
+                jnp.full((r_rounds, s_scan), -1, dtype=jnp.int32),
                 jnp.zeros((r_rounds,), dtype=jnp.int32),
                 jnp.zeros((), dtype=jnp.int32),
             )
@@ -1585,20 +1601,37 @@ def _columns_pass(
                 # each failed attempt adds at least one column, and a chunk
                 # can register at most chunk_size witnesses, so this bound
                 # is safe even for degenerate one-round-per-event DAGs
-                # (2-member gossip)
+                # (2-member gossip); slot grows re-run inside an attempt
                 for _attempt in range(chunk_size + 1):
-                    out = obs.stage_call(
-                        "pipeline.rounds_chunk_stage",
-                        rounds_chunk_stage,
-                        parents_d, ssm_c, jnp.asarray(col_pos), creator_d,
-                        stake_d, n_d, *state, start_d,
-                        jnp.zeros((), dtype=jnp.int32),
-                        tot_stake=tot, r_max=r_rounds, s_max=s_max,
-                        has_forks=has_forks, chunk=chunk_size,
-                    )
-                    n_scans += 1
-                    obs.tally("rounds_probes")
-                    tab = obs.to_host(out[2])
+                    while True:
+                        out = obs.stage_call(
+                            "pipeline.rounds_chunk_stage",
+                            rounds_chunk_stage,
+                            parents_d, ssm_c, jnp.asarray(col_pos),
+                            creator_d, stake_d, n_d, *state, start_d,
+                            jnp.zeros((), dtype=jnp.int32),
+                            tot_stake=tot, r_max=r_rounds, s_max=s_scan,
+                            has_forks=has_forks, chunk=chunk_size,
+                        )
+                        n_scans += 1
+                        obs.tally("rounds_probes")
+                        tab = obs.to_host(out[2])
+                        # a slot overflow leaves some round's last slot
+                        # filled, so only then is the flag worth a pull
+                        if not ((tab[:, -1] >= 0).any()
+                                and int(obs.to_host(out[4])) == OVF_SLOT):
+                            break
+                        _, s_new = _healed_capacities(
+                            OVF_SLOT, r_eff=r_rounds, r_cap=r_cap,
+                            s_eff=s_scan, s_cap=n_pad,
+                        )
+                        state = (*state[:2], jnp.pad(
+                            state[2], ((0, 0), (0, s_new - s_scan)),
+                            constant_values=-1), *state[3:])
+                        s_scan = s_new
+                        slot_grows += 1
+                        overflow_retries += 1
+                        rsp.args["slots"] = s_scan
                     registered = np.unique(tab[tab >= 0])
                     missing = registered[col_pos[registered] < 0]
                     if missing.size == 0:
@@ -1639,18 +1672,19 @@ def _columns_pass(
             ovf = int(obs.to_host(state[4]))
             if not ovf:
                 break
-            r_rounds, s_max = _healed_capacities(
-                ovf, r_eff=r_rounds, r_cap=r_cap, s_eff=s_max,
+            r_rounds, s_scan = _healed_capacities(
+                ovf, r_eff=r_rounds, r_cap=r_cap, s_eff=s_scan,
                 s_cap=n_pad,
             )
             overflow_retries += 1
-            rsp.args["slots"] = s_max
+            rsp.args["slots"] = s_scan
+    obs.tally("rounds_slot_grows", slot_grows)
     rnd_a, wits_a, tab_a, cnt_a, _overflow_a = state
     with obs.span("swirld.fame"):
         max_round_d = jnp.max(jnp.where(jnp.arange(n_pad) < n_d, rnd_a, 0))
         max_round = int(obs.to_host(max_round_d))
         r_tight = min(r_rounds, _bucket(max_round + 3, 8))
-        s_tight = _fame_slots(cnt_a, r_tight, s_max, len(packed.fork_pairs))
+        s_tight = _fame_slots(cnt_a, r_tight, s_scan, len(packed.fork_pairs))
         tab_b = tab_a[:r_tight, :s_tight]
         stage_b = obs.stage_call(
             "pipeline.fame_order_cols_stage",
@@ -1675,7 +1709,7 @@ def _columns_pass(
     aux = {
         "anc": anc, "sees": sees, "ssm_c": ssm_c,
         "col_pos": col_pos, "n_cols": n_cols, "w_cap": w_cap,
-        "n_scans": n_scans, "r_rounds": r_rounds, "s_max": s_max,
+        "n_scans": n_scans, "r_rounds": r_rounds, "s_max": s_scan,
         "overflow_retries": overflow_retries,
     }
     return out, aux
