@@ -40,9 +40,7 @@ All detail goes to stderr.  Environment knobs:
     BENCH_DEFAULT_STREAM_MEMBERS (48)  BENCH_DEFAULT_STREAM_EVENTS (6000)
     BENCH_DEFAULT_STREAM_CHUNK (1024) — the default (no-flags) run's
     always-on scaled-down streaming leg, so stream.evps lands in every
-    artifact (0 events disables); fusion/overlap knobs via
-    SWIRLD_FUSE_CHUNKS / SWIRLD_DECODE_OVERLAP /
-    SWIRLD_DECODE_QUEUE_DEPTH.
+    artifact (0 events disables).
     BENCH_STREAM_REF (20000) — with --mesh: events for the in-run
     single-device reference pass (0 disables); BENCH_STREAM_SINGLE_EVPS
     supplies the reference throughput externally instead (e.g. from a
@@ -329,7 +327,7 @@ def run_default():
     # the batch pipeline over the same events.
     stream_out = None
     if DEFAULT_STREAM_EVENTS > 0:
-        from tpu_swirld.config import SwirldConfig, resolve_stream_settings
+        from tpu_swirld.config import SwirldConfig
         from tpu_swirld.sim import stream_gossip_dag
         from tpu_swirld.store import StreamingConsensus
 
@@ -344,8 +342,6 @@ def run_default():
         with o.tracer.span("stream_default_ref"), \
                 mon.phase("stream_default_ref"):
             s_ref = run_consensus(s_packed, s_cfg)
-
-        settings = resolve_stream_settings(s_cfg)
 
         with o.tracer.span("stream_default"), mon.phase("stream_default"):
             eng = StreamingConsensus(
@@ -371,16 +367,12 @@ def run_default():
         s_evps = DEFAULT_STREAM_EVENTS / t_s
         log(f"[stream-default] {DEFAULT_STREAM_EVENTS} ev x "
             f"{DEFAULT_STREAM_MEMBERS} members in {t_s:.2f}s = "
-            f"{s_evps:.0f} ev/s fuse={settings['fuse_chunks']} "
-            f"decode_overlap={settings['decode_overlap']} "
-            f"parity={s_parity}")
+            f"{s_evps:.0f} ev/s parity={s_parity}")
         stream_out = {
             "evps": round(s_evps, 1),
             "members": DEFAULT_STREAM_MEMBERS,
             "events": DEFAULT_STREAM_EVENTS,
             "chunk": DEFAULT_STREAM_CHUNK,
-            "fuse_chunks": settings["fuse_chunks"],
-            "decode_overlap": settings["decode_overlap"],
             "decoded_off_thread": eng.decoded_off_thread,
             "ordered": len(s_res.order),
             "parity": bool(s_parity),

@@ -1,9 +1,12 @@
 """Aux subsystems: checkpoint/resume, metrics, viz export (SURVEY §5)."""
 
 import json
+import struct
+
+import pytest
 
 from tpu_swirld.checkpoint import (
-    load_node, load_packed, save_node, save_packed,
+    RETIRED_CONFIG_KEYS, load_node, load_packed, save_node, save_packed,
 )
 from tpu_swirld.metrics import Metrics, node_gauges
 from tpu_swirld.packing import pack_node
@@ -48,6 +51,41 @@ def test_node_checkpoint_resume_and_continue(tmp_path):
     new_ids = restored.sync(peer, b"resumed")
     restored.consensus_pass(new_ids)
     assert restored.head in restored.hg
+
+
+def _add_config_keys(path, **extra):
+    """Rewrite a node checkpoint's header with extra config keys."""
+    with open(path, "rb") as f:
+        data = f.read()
+    (hlen,) = struct.unpack_from("<I", data, 4)
+    meta = json.loads(data[8 : 8 + hlen].decode())
+    meta["config"].update(extra)
+    header = json.dumps(meta).encode()
+    with open(path, "wb") as f:
+        f.write(b"SWCK" + struct.pack("<I", len(header)) + header
+                + data[8 + hlen :])
+
+
+def test_node_checkpoint_header_with_retired_config_keys(tmp_path):
+    """Headers written while SwirldConfig still had the rounds-span and
+    decode settings carry them: they load, and any other unknown config
+    key is still refused."""
+    sim = make_simulation(4, seed=8)
+    sim.run(150)
+    node = sim.nodes[1]
+    p = str(tmp_path / "node.swck")
+    save_node(p, node)
+    _add_config_keys(p, fuse_chunks=8, decode_overlap=True,
+                     decode_queue_depth=2)
+    assert RETIRED_CONFIG_KEYS == (
+        "fuse_chunks", "decode_overlap", "decode_queue_depth",
+    )
+    restored = load_node(p, sk=node.sk, pk=node.pk, network=sim.network)
+    assert restored.consensus == node.consensus
+    assert restored.famous == node.famous
+    _add_config_keys(p, not_a_setting=1)
+    with pytest.raises(TypeError, match="not_a_setting"):
+        load_node(p, sk=node.sk, pk=node.pk, network=sim.network)
 
 
 def test_metrics_counters():

@@ -463,33 +463,20 @@ def _i_ssm_update(env):
     )
 
 
-def _i_rounds_chunk(env):
-    from tpu_swirld.tpu import pipeline as P
-
-    d = _dims(env)
-    W, C, M, R, S, N = d["W"], d["C"], d["M"], d["R"], d["S"], d["N"]
-    decls = _rounds_chunk_decls(W, C, M, R, S, d["chunk"], N - 1)
-    decls[4] = _arr((M,), _I32, 0, d["smax"])
-    return (
-        P.rounds_chunk_stage,
-        dict(tot_stake=d["tot"], r_max=R, s_max=S, has_forks=True,
-             chunk=d["chunk"]),
-        decls,
-    )
-
-
 def _i_rounds_span(env):
-    """Fused K-chunk rounds megadispatch: same carry/decl contract as
-    ``rounds_chunk_stage`` but the scan covers ``chunk * k_chunks``
-    events per dispatch, so the start scalar's bound tightens to
-    ``W - chunk*k`` (the driver only launches spans that fit the
-    window) and the interval proof must hold over the widest fused
-    trip count the default config can issue (k = 8)."""
+    """Fused K-chunk rounds megadispatch, the incremental rounds scan:
+    same carry/decl contract as ``rounds_chunk_stage`` but the scan
+    covers ``chunk * k_chunks`` events per dispatch, so the start
+    scalar's bound tightens to ``W - chunk*k`` (the driver only launches
+    spans that fit the window) and the interval proof must hold over the
+    widest fused trip count the driver issues (k = ``SPAN_CHUNKS``).
+    The rebase's ``_columns_pass`` dispatches ``rounds_chunk_stage`` at
+    batch shapes, which ``batch.rounds_chunk`` audits."""
     from tpu_swirld.tpu import pipeline as P
 
     d = _dims(env)
     W, C, M, R, S, N = d["W"], d["C"], d["M"], d["R"], d["S"], d["N"]
-    k_chunks = min(8, max(1, W // d["chunk"]))
+    k_chunks = min(P.SPAN_CHUNKS, max(1, W // d["chunk"]))
     decls = _rounds_chunk_decls(
         W, C, M, R, S, d["chunk"] * k_chunks, N - 1
     )
@@ -750,8 +737,6 @@ CATALOG: List[StageSpec] = [
               _INC, functools.partial(_i_ssm_block_from_rows, k=1)),
     StageSpec("inc.ssm_update", "pipeline.inc_ssm_update",
               _INC, _i_ssm_update),
-    StageSpec("inc.rounds_chunk", "pipeline.rounds_chunk_stage",
-              _INC, _i_rounds_chunk),
     StageSpec("inc.rounds_span", "pipeline.rounds_span_stage",
               _INC, _i_rounds_span),
     StageSpec("inc.fame", "pipeline.inc_fame", _INC, _i_fame),

@@ -44,6 +44,11 @@ from tpu_swirld.packing import PackedDAG
 
 FORMAT_VERSION = 1
 
+#: engine settings that older headers carry in their config and that
+#: SwirldConfig no longer has: dropped on load, any other unknown key is
+#: still refused
+RETIRED_CONFIG_KEYS = ("fuse_chunks", "decode_overlap", "decode_queue_depth")
+
 
 def _pack_bytes_list(items: List[bytes]) -> np.ndarray:
     """Length-prefixed flat uint8 array (no pickle)."""
@@ -188,6 +193,8 @@ def load_node(
     if meta["format_version"] != FORMAT_VERSION:
         raise ValueError(f"unsupported checkpoint version {meta['format_version']}")
     cfg_dict = dict(meta["config"])
+    for key in RETIRED_CONFIG_KEYS:
+        cfg_dict.pop(key, None)
     cfg_dict["stake"] = tuple(cfg_dict["stake"])
     cfg = SwirldConfig(**cfg_dict)
     members = [bytes.fromhex(m) for m in meta["members"]]

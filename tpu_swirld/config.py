@@ -144,38 +144,6 @@ def resolve_soak_settings(config: Optional["SwirldConfig"] = None) -> Dict:
     return out
 
 
-#: built-in streaming-dispatch defaults (field -> (env var, default,
-#: parser)).  Same precedence as the archive knobs: explicit SwirldConfig
-#: field > SWIRLD_* env var > built-in default.
-_STREAM_ENV = {
-    "fuse_chunks": ("SWIRLD_FUSE_CHUNKS", 8, int),
-    "decode_overlap": (
-        "SWIRLD_DECODE_OVERLAP", True,
-        lambda v: v.strip().lower() not in ("0", "", "no", "false", "off"),
-    ),
-    "decode_queue_depth": ("SWIRLD_DECODE_QUEUE_DEPTH", 2, int),
-}
-
-
-def resolve_stream_settings(config: Optional["SwirldConfig"] = None) -> Dict:
-    """Concrete streaming-dispatch settings: explicit config field >
-    ``SWIRLD_FUSE_CHUNKS`` / ``SWIRLD_DECODE_*`` env var > built-in
-    default.  Returns ``{"fuse_chunks", "decode_overlap",
-    "decode_queue_depth"}`` (plain values, never ``None``).
-    ``fuse_chunks <= 1`` disables dispatch fusion (the per-chunk loop);
-    ``decode_overlap`` toggles the streaming driver's gossip-decode
-    worker (results are identical either way — drain barriers serialize
-    every packer handoff)."""
-    out = {}
-    for field, (env, default, parse) in _STREAM_ENV.items():
-        v = getattr(config, field, None) if config is not None else None
-        if v is None:
-            raw = os.environ.get(env)
-            v = parse(raw) if raw is not None else default
-        out[field] = v
-    return out
-
-
 def resolve_archive_settings(config: Optional["SwirldConfig"] = None) -> Dict:
     """Concrete archive settings: explicit config field > ``SWIRLD_ARCHIVE_*``
     env var > built-in default.  Returns ``{"compress_level", "queue_depth",
@@ -273,21 +241,6 @@ class SwirldConfig:
                                                   # are identical either way —
                                                   # drain barriers serialize
                                                   # every read)
-
-    # --- streaming dispatch fusion / ingest-decode overlap ---
-    # None = fall back to SWIRLD_FUSE_CHUNKS / SWIRLD_DECODE_* env var,
-    # then built-in default (resolve_stream_settings).
-    fuse_chunks: Optional[int] = None   # scan chunks fused per rounds
-                                        # dispatch (<=1 = per-chunk loop;
-                                        # default 8).  Outputs are bit-
-                                        # identical at every value.
-    decode_overlap: Optional[bool] = None   # streaming gossip-decode
-                                            # worker on/off (default on;
-                                            # async == sync bit-identical
-                                            # — drain barriers serialize
-                                            # every packer handoff)
-    decode_queue_depth: Optional[int] = None  # bounded decode-queue depth
-                                              # (double-buffer default 2)
 
     # --- black-box flight recorder (obs.flightrec) ---
     # None = fall back to SWIRLD_FLIGHTREC_* env var, then built-in

@@ -50,13 +50,16 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from tpu_swirld import obs
-from tpu_swirld.config import resolve_stream_settings
 from tpu_swirld.packing import chunk_slices, prepare_events
 from tpu_swirld.store.slab import SlabStore
 from tpu_swirld.tpu.pipeline import (
     IncrementalConsensus,
     _bucket,
 )
+
+#: ingest chunks the decode worker may run ahead of the one the device
+#: executes (a double buffer)
+DECODE_QUEUE_DEPTH = 2
 
 
 class StreamingConsensus(IncrementalConsensus):
@@ -97,15 +100,13 @@ class StreamingConsensus(IncrementalConsensus):
             )
         )
         self._ingest_chunk = _bucket(max(ingest_chunk, 1), self._chunk)
-        # decode overlap: pre-hash the NEXT ingest chunk's event ids on a
+        # decode overlap: pre-hash the NEXT ingest chunks' event ids on a
         # worker thread while the device executes the current one.
-        # Results are bit-identical either way — the worker computes a
-        # pure function (prepare_events) and every handoff goes through a
-        # drain barrier (future.result(), which also re-raises worker
-        # failures); all packer mutation stays on the ingest thread.
-        _ss = resolve_stream_settings(self.config)
-        self._decode_overlap = bool(_ss["decode_overlap"])
-        self._decode_depth = max(1, int(_ss["decode_queue_depth"]))
+        # Results are bit-identical to decoding on this thread — the
+        # worker computes a pure function (prepare_events) and every
+        # handoff goes through a drain barrier (future.result(), which
+        # also re-raises worker failures); all packer mutation stays on
+        # the ingest thread.
         self._staged: Optional[List] = None  # pre-decoded next chunk
         self.decoded_off_thread = 0          # observability: events decoded
                                              # on the worker
@@ -174,8 +175,6 @@ class StreamingConsensus(IncrementalConsensus):
         self._account()
         arch = self.store.archive
         st["ingest_chunks"] = n_chunks
-        st["fuse_chunks"] = self._fuse
-        st["decode_overlap"] = self._decode_overlap
         st["resident_bytes"] = self.resident_visibility_bytes
         st["archived_rows"] = arch.n_rows
         st["overlap_ratio"] = round(overlap, 4)
@@ -190,18 +189,20 @@ class StreamingConsensus(IncrementalConsensus):
     # ----------------------------------------------------- decode overlap
 
     def _chunked_deltas(self, events: List):
-        """Yield the delta's ingest chunks in order.  With decode overlap
-        on, one worker thread runs :func:`~tpu_swirld.packing.
-        prepare_events` (event-id hashing — the dominant host decode
-        cost) up to ``decode_queue_depth`` chunks ahead of the chunk the
-        device is executing.  Each yield first drains the worker's future
-        for that chunk (``future.result()`` — the barrier that also
-        re-raises any worker failure on the ingest thread) and stages the
-        pre-decoded pairs for :meth:`_pack_delta`; the worker never
-        touches the packer or any driver state, so async and sync
-        ingestion are bit-identical by construction."""
+        """Yield the delta's ingest chunks in order.  A delta of more
+        than one chunk is decoded by one worker thread, which runs
+        :func:`~tpu_swirld.packing.prepare_events` (event-id hashing —
+        the dominant host decode cost) up to ``DECODE_QUEUE_DEPTH``
+        chunks ahead of the chunk the device is executing; a delta of
+        one chunk is decoded on this thread by :meth:`_pack_delta`.
+        Each yield first drains the worker's future for that chunk
+        (``future.result()`` — the barrier that also re-raises any
+        worker failure on the ingest thread) and stages the pre-decoded
+        pairs for :meth:`_pack_delta`; the worker never touches the
+        packer or any driver state, so async and sync ingestion are
+        bit-identical by construction."""
         slices = chunk_slices(len(events), self._ingest_chunk)
-        if not (self._decode_overlap and len(slices) > 1):
+        if len(slices) <= 1:
             for s, e in slices:
                 yield events[s:e]
             return
@@ -218,7 +219,7 @@ class StreamingConsensus(IncrementalConsensus):
                         ex.submit(prepare_events, events[nxt[0]:nxt[1]])
                     )
 
-            for _ in range(min(self._decode_depth, len(slices))):
+            for _ in range(min(DECODE_QUEUE_DEPTH, len(slices))):
                 submit_next()
             while futs:
                 with obs.span("swirld.wait", on="decode"):

@@ -76,7 +76,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from tpu_swirld import crypto, obs
-from tpu_swirld.config import SwirldConfig, resolve_stream_settings
+from tpu_swirld.config import SwirldConfig
 from tpu_swirld.oracle.node import xor_bytes
 from tpu_swirld.packing import PackedDAG, Packer
 
@@ -88,6 +88,12 @@ INT32_MAX = np.iinfo(np.int32).max
 # slots were exhausted (OVF_SLOT).
 OVF_ROUND = 1
 OVF_SLOT = 2
+
+# Scan chunks one incremental rounds dispatch covers (rounds_span_stage):
+# a pass of n chunks issues ceil(n / SPAN_CHUNKS) spans, the last one
+# ragged.  The ragged-tail trip counts are compiled shapes, so changing
+# this changes what warm-up lowers.
+SPAN_CHUNKS = 8
 
 
 def _record_shapes(o, *, n: int, n_pad: int, statics: Dict) -> None:
@@ -919,6 +925,20 @@ def _suffix_rows(row_hi: int, row_lo: int, cap: int):
     return max(0, row_hi - rows), rows
 
 
+def _resume_rounds(parents, ssm_c, col_pos, creator, stake, n_valid,
+                   carry, start, r_base, *, tot_stake, r_max, s_max,
+                   has_forks, length):
+    """The rounds scan resumed from ``carry`` over events
+    [start, start+length): the one body both jitted rounds stages
+    trace."""
+    step = _make_rounds_step(
+        parents, ssm_c, creator, stake, tot_stake, n_valid, r_base,
+        r_max=r_max, s_max=s_max, has_forks=has_forks, col_pos=col_pos,
+    )
+    carry, _ = lax.scan(step, carry, start + jnp.arange(length))
+    return carry
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("tot_stake", "r_max", "s_max", "has_forks", "chunk"),
@@ -928,18 +948,16 @@ def rounds_chunk_stage(parents, ssm_c, col_pos, creator, stake, n_valid,
                        tot_stake, r_max, s_max, has_forks, chunk):
     """One chunk of the rounds scan: events [start, start+chunk) resume
     from the carried (rnd, wits, tab, cnt, overflow) state.  Shares the
-    per-event body with rounds_scan — used by the incremental
-    column-restricted path.  ``r_base`` (traced) maps global rounds to
-    witness-table rows (0 on the batch path)."""
-    step = _make_rounds_step(
-        parents, ssm_c, creator, stake, tot_stake, n_valid, r_base,
-        r_max=r_max, s_max=s_max, has_forks=has_forks, col_pos=col_pos,
+    per-event body with rounds_scan — used by the column-restricted
+    batch path (``_columns_pass``, which the incremental rebase runs
+    too).  ``r_base`` (traced) maps global rounds to witness-table rows
+    (0 on the batch path)."""
+    return _resume_rounds(
+        parents, ssm_c, col_pos, creator, stake, n_valid,
+        (rnd, wits, tab, cnt, overflow), start, r_base,
+        tot_stake=tot_stake, r_max=r_max, s_max=s_max,
+        has_forks=has_forks, length=chunk,
     )
-    carry0 = (rnd, wits, tab, cnt, overflow)
-    (rnd, wits, tab, cnt, overflow), _ = lax.scan(
-        step, carry0, start + jnp.arange(chunk)
-    )
-    return rnd, wits, tab, cnt, overflow
 
 
 @functools.partial(
@@ -959,15 +977,12 @@ def rounds_span_stage(parents, ssm_c, col_pos, creator, stake, n_valid,
     positions 6-10) are donated: callers re-upload the host-mirror carry
     before every probe, so the witness-column fixpoint retry never reads
     a buffer this dispatch consumed."""
-    step = _make_rounds_step(
-        parents, ssm_c, creator, stake, tot_stake, n_valid, r_base,
-        r_max=r_max, s_max=s_max, has_forks=has_forks, col_pos=col_pos,
+    return _resume_rounds(
+        parents, ssm_c, col_pos, creator, stake, n_valid,
+        (rnd, wits, tab, cnt, overflow), start, r_base,
+        tot_stake=tot_stake, r_max=r_max, s_max=s_max,
+        has_forks=has_forks, length=chunk * k_chunks,
     )
-    carry0 = (rnd, wits, tab, cnt, overflow)
-    (rnd, wits, tab, cnt, overflow), _ = lax.scan(
-        step, carry0, start + jnp.arange(chunk * k_chunks)
-    )
-    return rnd, wits, tab, cnt, overflow
 
 
 @functools.partial(
@@ -2092,7 +2107,6 @@ class IncrementalConsensus:
         storm_threshold: int = 3,
         storm_cooldown: int = 8,
         slab_put=None,
-        fuse_chunks: Optional[int] = None,
     ):
         if stake is None:
             stake = [1] * len(members)
@@ -2100,13 +2114,6 @@ class IncrementalConsensus:
         self.config = config or SwirldConfig(n_members=len(members))
         self._block = block
         self._chunk = max(32, chunk)
-        # dispatch fusion: how many rounds-scan chunks one device
-        # dispatch covers (rounds_span_stage).  <= 1 keeps the original
-        # per-chunk loop; resolution order is explicit kwarg > config
-        # field > SWIRLD_FUSE_CHUNKS env > default (see config module)
-        if fuse_chunks is None:
-            fuse_chunks = resolve_stream_settings(self.config)["fuse_chunks"]
-        self._fuse = max(1, int(fuse_chunks))
         self._window_bucket = max(256, window_bucket)
         self._prune_min = (
             prune_min if prune_min is not None else self._window_bucket // 4
@@ -2668,27 +2675,30 @@ class IncrementalConsensus:
 
     def _rounds_span_fixpoint(self, parents_d, creator_d, stake_d, n_valid,
                               has_forks, w0, n_pad_new, r_base_d):
-        """Fused rounds scan: spans of up to ``self._fuse`` chunks per
-        dispatch (``rounds_span_stage``), each run to a witness-column
-        fixpoint.  Returns the accepted final carry (device tuple, same
-        layout as the unfused loop's ``state``) or ``None`` on round/slot
-        overflow — the caller rebases, which is exact because the unfused
-        path also commits nothing once its sticky overflow bit is set.
+        """The incremental rounds scan: spans of up to ``SPAN_CHUNKS``
+        chunks per dispatch (``rounds_span_stage``), each run to a
+        witness-column fixpoint.  Returns the accepted final carry
+        (device tuple ``(rnd, wits, tab, cnt, overflow)``) or ``None``
+        on round/slot overflow — the caller rebases, which is exact
+        because ``_columns_pass`` likewise commits nothing once its
+        sticky overflow bit is set, and regrows the flagged capacity.
 
-        Exactness vs the per-chunk loop: every probe re-runs the whole
+        Exactness vs the batch result: every probe re-runs the whole
         span from the SAME host-mirror carry, and a probe is accepted
         only when every witness registered anywhere in its output table
         already had a strongly-sees column for the entire run.  A missing
         column deterministically reads as not-strongly-seen (the scan
         body masks ``col_pos < 0`` — under-promotion only, never
         garbage), so an accepted run never consumed a value the
-        fully-informed run wouldn't produce; its outputs are therefore
-        bit-identical to running the chunks one dispatch at a time.
+        fully-informed scan wouldn't produce; its outputs are therefore
+        bit-identical to ``_columns_pass`` resuming the same events one
+        chunk at a time, and so to :func:`run_consensus`.
         Each failed probe registers >= 1 event whose column is absent
         and ``_add_columns`` makes it present, so columns grow strictly
         monotonically and the loop terminates within span_len probes.
-        A ragged tail (n_chunks % fuse != 0) gets its own static
-        ``k_chunks`` — a session-bounded shape family (< fuse values).
+        A ragged tail (n_chunks % SPAN_CHUNKS != 0) gets its own static
+        ``k_chunks`` — a session-bounded shape family (< SPAN_CHUNKS
+        values).
         """
         chunk = self._chunk
         n_chunks = n_pad_new // chunk
@@ -2699,7 +2709,7 @@ class IncrementalConsensus:
         state = None
         ci = 0
         while ci < n_chunks:
-            k = min(self._fuse, n_chunks - ci)
+            k = min(SPAN_CHUNKS, n_chunks - ci)
             start = np.int32(w0 + ci * chunk)
             span_len = k * chunk
             for _attempt in range(span_len + 1):
@@ -2886,83 +2896,21 @@ class IncrementalConsensus:
         with obs.span("swirld.rounds", slots=self._s_cap,
                       forked=has_forks):
             r_base_d = np.int32(self._r_base)
-            if self._fuse > 1:
-                state = self._rounds_span_fixpoint(
-                    parents_d, creator_d, stake_d, n_valid, has_forks,
-                    w0, n_pad_new, r_base_d,
-                )
-                if state is None:
-                    # round/slot capacity overflow mid-span -> rebase now;
-                    # the unfused path also commits nothing on overflow, so
-                    # skipping the remaining spans is exact
-                    return [], True
-            else:
-                state = (
-                    jnp.asarray(self._rnd_w),
-                    jnp.asarray(self._wits_w),
-                    jnp.asarray(self._tab_np),
-                    jnp.asarray(self._cnt_np),
-                    jnp.zeros((), dtype=jnp.int32),
-                )
-                for start in range(w0, w0 + n_pad_new, chunk):
-                    for _attempt in range(chunk + 1):
-                        out = obs.stage_call(
-                            "pipeline.rounds_chunk_stage",
-                            rounds_chunk_stage, parents_d, self._ssm_d,
-                            jnp.asarray(self._colpos_w), creator_d, stake_d,
-                            np.int32(n_valid), *state, np.int32(start),
-                            r_base_d, tot_stake=self._tot,
-                            r_max=self._r_cap, s_max=self._s_cap,
-                            has_forks=has_forks, chunk=chunk,
-                        )
-                        obs.tally("rounds_probes")
-                        tab = obs.to_host(out[2])
-                        registered = np.unique(tab[tab >= 0])
-                        missing = registered[self._colpos_w[registered] < 0]
-                        if missing.size == 0:
-                            state = out
-                            obs.tally("rounds_units")
-                            break
-                        rnd_np = obs.to_host(out[0])
-                        ce = np.arange(start, start + chunk, dtype=np.int64)
-                        pc = self._parents_w[ce]
-                        r0 = np.where(
-                            pc[:, 0] < 0,
-                            -1,
-                            np.maximum(rnd_np[np.maximum(pc[:, 0], 0)],
-                                       rnd_np[np.maximum(pc[:, 1], 0)]),
-                        )
-                        affected = False
-                        for w in missing:
-                            if w < start:
-                                affected = True
-                                break
-                            later = ce > w
-                            if np.any(later & (r0 == rnd_np[w])):
-                                affected = True
-                                break
-                        self._add_columns([int(e) for e in missing])
-                        obs.tally("columns_added", len(missing))
-                        if not affected:
-                            state = out
-                            obs.tally("rounds_units")
-                            break
-                    else:
-                        raise RuntimeError(
-                            "witness-column chunk did not converge"
-                        )
-
+            state = self._rounds_span_fixpoint(
+                parents_d, creator_d, stake_d, n_valid, has_forks,
+                w0, n_pad_new, r_base_d,
+            )
+            if state is None:
+                # round/slot capacity overflow -> rebase, which self-heals:
+                # _columns_pass grows the flagged capacity and the adopted
+                # window table inherits it (never a crash)
+                return [], True
             # copy=True (np.array, not asarray): device pulls are read-only
             # views, and these mirrors are mutated in place by roll/prune
             rnd_w = obs.to_host(state[0], copy=True)
             wits_w = obs.to_host(state[1], copy=True)
             tab_np = obs.to_host(state[2], copy=True)
             cnt_np = obs.to_host(state[3], copy=True)
-            if int(obs.to_host(state[4])):
-                # round/slot capacity overflow -> rebase, which self-heals:
-                # _columns_pass grows the flagged capacity and the adopted
-                # window table inherits it (never a crash)
-                return [], True
             _tally_rounds(self._fork_np.shape[0], self._s_cap,
                           int(cnt_np.max(initial=0)))
 
